@@ -17,6 +17,7 @@ from benchmarks import (bench_continued_training,  # noqa: E402
                         bench_sparsity_sweep, bench_target_sparsity,
                         roofline)
 from benchmarks.common import CACHE_DIR  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 BENCHES = [
     ("Table1/LongBench-E", bench_longbench_proxy),
@@ -38,6 +39,7 @@ BENCHES = [
 
 def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    enable_compile_cache()
     out_lines = ["name,us_per_call,derived"]
     print("name,us_per_call,derived")
     for label, mod in BENCHES:

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.distributed.sharding import over_heads
+
 NEG_INF = -1e30
 
 
@@ -303,27 +305,33 @@ def _chunk_causal_block_sparse(q: jax.Array, k: jax.Array, v: jax.Array,
     [0, last_vis(i)] and marks the rest -1, which the kernel skips via
     ``pl.when`` — dead blocks cost no MXU work.  ``start`` rides into
     the kernel as a traced scalar-prefetch operand (the causal offset),
-    so every chunk of a bucket still shares one executable."""
+    so every chunk of a bucket still shares one executable.  Under a
+    mesh the kernel runs per shard of the heads (``over_heads``)."""
     from repro.kernels.block_sparse_attention import \
         block_sparse_attention_bh
-    B, Hq, C, D = q.shape
-    Hkv, M = k.shape[1], k.shape[2]
-    nqb = -(-C // block)
-    K = -(-M // block)
-    qb = jnp.arange(nqb)
-    kb = jnp.arange(K)
-    # last kv block any live row of query block i can see; rows past C
-    # are padding (masked in-kernel), so bound by the last live row
-    last_vis = (start + jnp.minimum((qb + 1) * block, C) - 1) // block
-    sel = jnp.where(kb[None, :] <= last_vis[:, None], kb[None, :], -1)
-    sel = jnp.broadcast_to(sel[None], (B * Hq, nqb, K)).astype(jnp.int32)
-    out = block_sparse_attention_bh(
-        q.reshape(B * Hq, C, D), k.reshape(B * Hkv, M, D),
-        v.reshape(B * Hkv, M, D), sel, q_offset=start, scale=scale,
-        block=block,
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret))
-    return out.reshape(B, Hq, C, out.shape[-1])
+    interpret = (jax.default_backend() != "tpu"
+                 if interpret is None else interpret)
+
+    def call(q, k, v, start):
+        B, Hq, C, D = q.shape
+        Hkv, M = k.shape[1], k.shape[2]
+        nqb = -(-C // block)
+        K = -(-M // block)
+        qb = jnp.arange(nqb)
+        kb = jnp.arange(K)
+        # last kv block any live row of query block i can see; rows
+        # past C are padding (masked in-kernel), so bound by the last
+        # live row
+        last_vis = (start + jnp.minimum((qb + 1) * block, C) - 1) // block
+        sel = jnp.where(kb[None, :] <= last_vis[:, None], kb[None, :], -1)
+        sel = jnp.broadcast_to(sel[None], (B * Hq, nqb, K)).astype(jnp.int32)
+        out = block_sparse_attention_bh(
+            q.reshape(B * Hq, C, D), k.reshape(B * Hkv, M, D),
+            v.reshape(B * Hkv, M, D), sel, q_offset=start, scale=scale,
+            block=block, interpret=interpret)
+        return out.reshape(B, Hq, C, out.shape[-1])
+
+    return over_heads(call, q.shape[1], k.shape[1])(q, k, v, start)
 
 
 def chunk_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,

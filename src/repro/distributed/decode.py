@@ -15,29 +15,12 @@ HOW a full cache is read at 500K.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-compat wrapper: the replication-check kwarg was renamed
-    ``check_rep`` → ``check_vma`` across jax versions."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
 
 
 def lse_combine_decode(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -70,7 +53,7 @@ def lse_combine_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         return out.reshape(B, Hq, 1, D).astype(qb.dtype)
 
     kv_spec = P(None, None, axes, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), kv_spec, kv_spec, P(axes)),
         out_specs=P(),
@@ -79,12 +62,10 @@ def lse_combine_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _flat_axis_index(kv_axes: Tuple[str, ...]):
-    """Row-major flat shard index over possibly-multiple mesh axes.
-    ``psum(1, axis)`` is the axis size on every jax version
-    (``lax.axis_size`` only exists on newer releases)."""
+    """Row-major flat shard index over possibly-multiple mesh axes."""
     idx = lax.axis_index(kv_axes[0])
     for a in kv_axes[1:]:
-        idx = idx * lax.psum(1, a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -113,7 +94,7 @@ def sharded_seq_insert(cache_k: jax.Array, cache_v: jax.Array,
         return (jnp.where(mine, ck_upd, ck), jnp.where(mine, cv_upd, cv))
 
     kv_spec = P(None, None, axes, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(kv_spec, kv_spec, P(), P(), P()),
         out_specs=(kv_spec, kv_spec),

@@ -1,7 +1,7 @@
 """Mesh-agnostic sharding hints.
 
 Model code calls ``constrain(x, *axes)`` with *logical* axis names; the
-launch layer activates a mesh (``jax.sharding.use_mesh``) and a logical→
+launch layer activates a mesh (``jax.set_mesh``) and a logical→
 mesh-axis mapping (``logical_rules``).  Outside any mesh context the
 hints are no-ops, so unit tests and CPU smoke runs are unaffected.
 
@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
@@ -37,17 +37,10 @@ def logical_rules(rules: Dict[str, Optional[Tuple[str, ...]]]):
         _state.rules = prev
 
 
-def _live_mesh():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    try:
-        if get_abstract is not None:
-            mesh = get_abstract()
-        else:  # jax 0.4/0.5: the legacy ``with mesh:`` ambient mesh
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", True) or not mesh.axis_names:
+def live_mesh():
+    """The mesh ``jax.set_mesh`` made ambient, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return None
     return mesh
 
@@ -58,7 +51,7 @@ def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     rules = _rules()
     if rules is None:
         return x
-    mesh = _live_mesh()
+    mesh = live_mesh()
     if mesh is None:
         return x
     spec = []
@@ -88,3 +81,29 @@ def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
         return jax.lax.with_sharding_constraint(x, P(*spec))
     except (ValueError, TypeError):
         return x
+
+
+def over_heads(fn: Callable, n_heads: int, n_kv_heads: int) -> Callable:
+    """Run a Mosaic (Pallas TPU) attention call ``fn(q, k, v, *rest)``
+    under the live mesh.
+
+    The compiler cannot partition a Mosaic kernel, so under a mesh the
+    call must sit inside a ``shard_map``.  Attention heads are
+    independent, so when the "model" axis divides both the query and
+    the KV heads each device runs ``fn`` on its own heads — q, k, v and
+    the output split on dim 1, ``rest`` replicated — and no collective
+    is needed.  When it does not divide them, every device runs the
+    whole call on replicated operands.  Outside a mesh ``fn`` is
+    returned as is."""
+    mesh = live_mesh()
+    if mesh is None:
+        return fn
+    model = mesh.shape.get("model", 1)
+    split = model > 1 and n_heads % model == 0 and n_kv_heads % model == 0
+    heads = P(None, "model") if split else P()
+
+    def wrapped(*args):
+        specs = (heads,) * 3 + (P(),) * (len(args) - 3)
+        return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                             out_specs=heads, check_vma=False)(*args)
+    return wrapped

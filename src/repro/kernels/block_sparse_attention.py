@@ -17,9 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names it TPUCompilerParams; jax >= 0.6 renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.kernels.decode_attention import pv_precision
 
 NEG_INF = -1e30
 
@@ -64,6 +62,7 @@ def _kernel(sel_ref, off_ref, q_ref, k_ref, v_ref, o_ref, acc, m_scr,
         l_scr[...] = l_scr[...] * alpha + p.sum(-1, keepdims=True)
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            precision=pv_precision(v_ref.dtype),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
@@ -134,7 +133,7 @@ def block_sparse_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((BH, Sq_p, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(sel.astype(jnp.int32), off, q, k, v)

@@ -37,11 +37,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names it TPUCompilerParams; jax >= 0.6 renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.distributed.sharding import over_heads
 
 NEG_INF = -1e30
+
+
+def pv_precision(dtype):
+    """Precision of the p·v product: one MXU pass for bf16 operands,
+    which is exact for them — Mosaic refuses the fp32 contract a
+    process-wide "highest" matmul precision would otherwise ask for."""
+    return jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
 
 
 @dataclass(frozen=True)
@@ -95,6 +100,7 @@ def _kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref, acc, m_scr, l_scr,
     l_scr[...] = l_scr[...] * alpha + p.sum(-1, keepdims=True)
     acc[...] = acc[...] * alpha + jax.lax.dot_general(
         p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        precision=pv_precision(v_ref.dtype),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
@@ -139,7 +145,7 @@ def decode_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, pos, cur)
@@ -148,13 +154,14 @@ def decode_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                    acc, m_scr, l_scr, *, scale: float, block_k: int,
-                   n_heads: int):
+                   n_heads: int, k_seq_minor: bool, v_seq_minor: bool):
     """Grid (B·Hq, max-blocks); row b serves (batch b//Hq, head b%Hq).
 
     ``len_ref`` (scalar prefetch, (B,)) is the live-prefix length per
     slot; blocks past ``ceil(n / block_k)`` are short-circuited — their
     KV fetch was already clamped onto the last live block by the index
-    map, so skipped steps cost neither HBM bytes nor FLOPs."""
+    map, so skipped steps cost neither HBM bytes nor FLOPs.  A
+    ``*_seq_minor`` block arrives as (D, bk) instead of (bk, D)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -170,14 +177,15 @@ def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     @pl.when(j < nb)
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # (1, Dk) — single token
-        k = k_ref[0].astype(jnp.float32)  # (bk, Dk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k = k_ref[0].astype(jnp.float32)  # (bk, Dk) or (Dk, bk)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (0 if k_seq_minor else 1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
         col = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         # live prefix ∧ occupied ring entry (-1 = empty); FullKV rows
         # carry arange positions so only the prefix bound bites
-        mask = (pos_ref[...] >= 0) & (col < n)
+        mask = (pos_ref[0] >= 0) & (col < n)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]
@@ -186,7 +194,9 @@ def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + p.sum(-1, keepdims=True)
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0],
+            (((1,), (1 if v_seq_minor else 0,)), ((), ())),
+            precision=pv_precision(v_ref.dtype),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
@@ -221,8 +231,21 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
         positions = jnp.pad(positions.astype(jnp.int32),
                             ((0, 0), (0, L_p - L)), constant_values=-1)
     lengths = jnp.minimum(lengths.astype(jnp.int32), L)
-    positions = positions.astype(jnp.int32)
+    # (B, 1, L_p): a (1, 1, block_k) block keeps its last two dims equal
+    # to the array's / a multiple of 128, the TPU block-shape rule that
+    # a (1, block_k) block over a (B, L_p) array breaks for B > 1
+    positions = positions.astype(jnp.int32)[:, None, :]
     grid = (BH, L_p // block_k)
+    # A TPU array whose head dim is not a multiple of 128 lanes is laid
+    # out with its sequence dim minor (no lane padding).  Reading such a
+    # K or V as (D, block_k) blocks of its (D, L) view makes the swap a
+    # relabelling of the same bytes; (block_k, D) blocks would make the
+    # compiler copy the whole cache into a lane-padded layout per call.
+    k_seq_minor, v_seq_minor = Dk % 128 != 0, Dv % 128 != 0
+    if k_seq_minor:
+        k = jnp.swapaxes(k, 1, 2)
+    if v_seq_minor:
+        v = jnp.swapaxes(v, 1, 2)
 
     def live_block(b, j, len_ref):
         # clamp dead steps onto the last live block: the pipeline sees
@@ -235,20 +258,29 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
     def kv_map(b, j, len_ref):
         return (b // G, live_block(b, j, len_ref), 0)
 
+    def kv_map_seq_minor(b, j, len_ref):
+        return (b // G, 0, live_block(b, j, len_ref))
+
+    def kv_spec(D, seq_minor):
+        if seq_minor:
+            return pl.BlockSpec((1, D, block_k), kv_map_seq_minor)
+        return pl.BlockSpec((1, block_k, D), kv_map)
+
     def pos_map(b, j, len_ref):
-        return (b // n_heads, live_block(b, j, len_ref))
+        return (b // n_heads, 0, live_block(b, j, len_ref))
 
     out = pl.pallas_call(
         functools.partial(_pooled_kernel, scale=scale, block_k=block_k,
-                          n_heads=n_heads),
+                          n_heads=n_heads, k_seq_minor=k_seq_minor,
+                          v_seq_minor=v_seq_minor),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, Dk), lambda b, j, lens: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, Dk), kv_map),
-                pl.BlockSpec((1, block_k, Dv), kv_map),
-                pl.BlockSpec((1, block_k), pos_map),
+                kv_spec(Dk, k_seq_minor),
+                kv_spec(Dv, v_seq_minor),
+                pl.BlockSpec((1, 1, block_k), pos_map),
             ],
             out_specs=pl.BlockSpec((1, 1, Dv), lambda b, j, lens: (b, 0, 0)),
             scratch_shapes=[
@@ -258,7 +290,7 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((BH, 1, Dv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k, v, positions)
@@ -278,6 +310,10 @@ def make_kernel_decode_attn(*, block_k: int = 128,
     carrying per-slot lengths/positions, which routes to the batched
     pooled kernel (FullKV, RingKV, and — via the q/k/v re-expression in
     ``attention.mla_absorbed_qkv`` — MLA absorbed decode).
+
+    Under a mesh (``jax.set_mesh``) each kernel call runs per shard of
+    the attention heads (``distributed.sharding.over_heads``): the
+    compiler cannot partition a Mosaic kernel by itself.
 
     Declines (→ dense fallback) when the cache is shorter than
     ``min_len`` (the dense dot wins on tiny rings) or when the mask
@@ -301,33 +337,47 @@ def make_kernel_decode_attn(*, block_k: int = 128,
             if L < min_len:
                 _note("decline", "min_len")
                 return None
-            B, Hq, _, Dk = q.shape
-            Hkv, Dv = k.shape[1], v.shape[3]
+            B = q.shape[0]
             if valid.positions is None:
                 positions = jnp.broadcast_to(
                     jnp.arange(L, dtype=jnp.int32)[None, :], (B, L))
             else:
                 positions = valid.positions
-            out = decode_attention_pooled_bh(
-                q.reshape(B * Hq, 1, Dk), k.reshape(B * Hkv, L, Dk),
-                v.reshape(B * Hkv, L, Dv), positions, valid.lengths,
-                n_heads=Hq, scale=scale, block_k=block_k,
-                interpret=interp)
-            _note("hit", "pooled")
-            return out.reshape(B, Hq, 1, Dv)
+
+            def pooled(q, k, v, positions, lengths):
+                Hq, Dk = q.shape[1], q.shape[3]
+                Hkv, Dv = k.shape[1], v.shape[3]
+                out = decode_attention_pooled_bh(
+                    q.reshape(B * Hq, 1, Dk), k.reshape(B * Hkv, L, Dk),
+                    v.reshape(B * Hkv, L, Dv), positions, lengths,
+                    n_heads=Hq, scale=scale, block_k=block_k,
+                    interpret=interp)
+                return out.reshape(B, Hq, 1, Dv)
+
+            # FullKV rows ship no positions; ring rows do
+            _note("hit", "pooled" if valid.positions is None
+                  else "pooled_ring")
+            return over_heads(pooled, q.shape[1], k.shape[1])(
+                q, k, v, positions, valid.lengths)
         if valid.ndim != 1 or k.shape[2] < min_len:
             _note("decline",
                   "mask_rank" if valid.ndim != 1 else "min_len")
             return None
-        B, Hq, _, D = q.shape
-        Hkv, L = k.shape[1], k.shape[2]
+        B, L = q.shape[0], k.shape[2]
         positions = jnp.where(valid, jnp.arange(L, dtype=jnp.int32), -1)
-        out = decode_attention_bh(
-            q.reshape(B * Hq, 1, D), k.reshape(B * Hkv, L, D),
-            v.reshape(B * Hkv, L, D), positions, jnp.int32(L),
-            scale=scale, block_k=block_k, interpret=interp)
+
+        def shared(q, k, v, positions):
+            Hq, D = q.shape[1], q.shape[3]
+            Hkv = k.shape[1]
+            out = decode_attention_bh(
+                q.reshape(B * Hq, 1, D), k.reshape(B * Hkv, L, D),
+                v.reshape(B * Hkv, L, D), positions, jnp.int32(L),
+                scale=scale, block_k=block_k, interpret=interp)
+            return out.reshape(B, Hq, 1, D)
+
         _note("hit", "shared")
-        return out.reshape(B, Hq, 1, D)
+        return over_heads(shared, q.shape[1], k.shape[1])(q, k, v,
+                                                          positions)
 
     def drain_log() -> List[Tuple[str, str]]:
         out = list(trace_log)

@@ -20,9 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names it TPUCompilerParams; jax >= 0.6 renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.kernels.decode_attention import pv_precision
 
 NEG_INF = -1e30
 
@@ -63,6 +61,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *, scale: float,
         l_scr[...] = l_scr[...] * alpha + p.sum(-1, keepdims=True)
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            precision=pv_precision(v_ref.dtype),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
@@ -108,7 +107,7 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

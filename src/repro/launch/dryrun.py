@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 for the production meshes, prove memory/sharding coherence, and record
 the roofline source numbers (EXPERIMENTS.md §Dry-run).
 
-The two lines above MUST precede any jax import — jax locks the device
-count at first init.  Run:
+The lines above MUST precede any jax import — jax locks the platform
+and device count at first init.  The dry-run is a CPU-only tool: it
+never claims an attached accelerator.  Run:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch phi3-mini-3.8b \
         --shape decode_32k [--multi-pod]
@@ -23,8 +25,7 @@ from repro.configs import ALL_ARCHS, SHAPES, get_config  # noqa: E402
 from repro.distributed import logical_rules  # noqa: E402
 from repro.launch import hlo_analysis as HA  # noqa: E402
 from repro.launch import workloads as WL  # noqa: E402
-from repro.launch.mesh import (make_production_mesh,  # noqa: E402
-                                mesh_context)
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                          "artifacts", "dryrun")
@@ -49,7 +50,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     try:
         wl = WL.build_workload(cfg, shape, mesh, **wl_kw)
         record["workload"] = wl.name
-        with mesh_context(mesh), logical_rules(wl.rules):
+        with jax.set_mesh(mesh), logical_rules(wl.rules):
             lowered = jax.jit(wl.fn, in_shardings=wl.in_shardings).lower(
                 *wl.args)
             t_lower = time.time() - t0

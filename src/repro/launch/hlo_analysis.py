@@ -70,11 +70,7 @@ def collective_bytes(hlo_text: str) -> CollectiveStats:
 
 def _cost_dict(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    return dict(ca) if ca is not None else {}
 
 
 def roofline_terms(compiled, hlo_text: str, n_chips: int,

@@ -16,22 +16,9 @@ import jax
 from jax.sharding import Mesh
 
 
-def mesh_context(mesh: Mesh):
-    """Context manager activating ``mesh`` for sharding constraints:
-    ``jax.set_mesh`` on jax >= 0.6, the legacy ``with mesh:`` context
-    on 0.4/0.5."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def _make_mesh(shape, axes, devices) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the jax
-    version has them (>= 0.5); older versions only have Auto axes."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

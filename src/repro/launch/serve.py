@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs import ALL_ARCHS, get_config, smoke_variant
 from repro.data.synthetic import SyntheticTasks
 from repro.kernels.decode_attention import make_kernel_decode_attn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.serve import (Request, ServeEngine, SLOConfig, STATUS_OK,
                          SHED_POLICIES, SHED_REJECT_NEWEST,
@@ -150,10 +151,12 @@ def _print_kernel_summary(engine: ServeEngine) -> None:
     if engine.decode_attn is None:
         return
     s = engine.decode_kernel_summary()
+    hits = " ".join(f"{r}={n}" for r, n in
+                    sorted(s["hit_reasons"].items())) or "none"
     declines = " ".join(f"{r}={n}" for r, n in
                         sorted(s["decline_layers"].items())) or "none"
     print(f"decode kernel: dispatches={s['dispatches']} "
-          f"hit_layers={s['hit_layers']} declines: {declines}")
+          f"hit_layers={s['hit_layers']} ({hits}) declines: {declines}")
 
 
 def _write_telemetry(engine: ServeEngine, args) -> None:
@@ -210,7 +213,7 @@ def main() -> None:
     ap.add_argument("--dense", action="store_true",
                     help="disable sparse decode (paper's non-shaded rows)")
     ap.add_argument("--decode-kernel", choices=("off", "auto", "on"),
-                    default="off",
+                    default="auto",
                     help="Pallas flash-decode backend for the decode "
                          "scan: 'auto' installs it and lets the adapter "
                          "decline per-layer (dense fallback below "
@@ -295,10 +298,19 @@ def main() -> None:
                          "before launch)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)
-    params = MD.init_params(jax.random.key(0), cfg)
+    mesh = None
+    if args.mesh_model > 1:
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(data=1, model=args.mesh_model)
+        print(f"mesh: (1, {args.mesh_model}) over "
+              f"{len(jax.devices())} devices")
+    # parameters are born in their serving shardings: no device ever
+    # holds a whole model that only fits the mesh
+    params = MD.init_params_on_device(jax.random.key(0), cfg, mesh)
     if args.load:
         params = checkpoint.load(args.load, params)
 
@@ -314,12 +326,6 @@ def main() -> None:
     telemetry = bool(args.metrics_out or args.trace_out)
     max_len = args.prompt_len + args.shared_prefix + args.gen_len + 8
     decode_attn = _decode_kernel(cfg, args, max_len)
-    mesh = None
-    if args.mesh_model > 1:
-        from repro.launch.mesh import make_debug_mesh
-        mesh = make_debug_mesh(data=1, model=args.mesh_model)
-        print(f"mesh: (1, {args.mesh_model}) over "
-              f"{len(jax.devices())} devices")
     engine = ServeEngine(params, cfg, max_len=max_len,
                          sparse_decode=not args.dense,
                          decode_attn=decode_attn,

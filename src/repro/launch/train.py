@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, smoke_variant
 from repro.data import mixture_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.train import PretrainTrainer, RouterTrainer, checkpoint
 
@@ -39,6 +40,7 @@ def main() -> None:
                     help="checkpoint to initialize the backbone from")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)

@@ -132,12 +132,14 @@ def _enc_block_init(key, cfg: ModelConfig) -> Dict[str, Any]:
 def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
     P, NP = period_len(cfg), n_periods(cfg)
     keys = jax.random.split(key, cfg.num_layers + cfg.num_encoder_layers + 2)
-    # trunk: for each period position, stack params over periods.
-    trunk = []
-    for pos in range(P):
-        per_period = [_block_init(keys[per * P + pos], cfg, per * P + pos)
-                      for per in range(NP)]
-        trunk.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_period))
+    # trunk: for each period position, params stacked over periods.  A
+    # vmap over the periods' keys builds each stacked leaf in place (the
+    # same values as initializing layer by layer and stacking, without
+    # holding every per-layer copy alongside the stack); every layer at
+    # one period position has the same block kind, so ``pos`` stands in
+    # for its layer index.
+    trunk = [jax.vmap(partial(_block_init, cfg=cfg, layer_idx=pos))(
+        keys[pos:NP * P:P]) for pos in range(P)]
     params: Dict[str, Any] = {
         "embed": embed_init(keys[-1], cfg.vocab_size, cfg.d_model,
                             cfg.param_dtype),
@@ -148,13 +150,31 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         params["out_w"] = dense_init(keys[-2], cfg.d_model, cfg.vocab_size,
                                      cfg.param_dtype)
     if cfg.num_encoder_layers:
-        enc = [_enc_block_init(keys[cfg.num_layers + i], cfg)
-               for i in range(cfg.num_encoder_layers)]
+        enc_keys = keys[cfg.num_layers:cfg.num_layers + cfg.num_encoder_layers]
         params["encoder"] = {
-            "layers": jax.tree.map(lambda *xs: jnp.stack(xs), *enc),
+            "layers": jax.vmap(partial(_enc_block_init, cfg=cfg))(enc_keys),
             "final_norm": rms_norm_init(cfg.d_model, cfg.param_dtype),
         }
     return params
+
+
+def init_params_on_device(key, cfg: ModelConfig, mesh=None) -> Dict[str, Any]:
+    """``init_params`` as one compiled program, its output placed where
+    serving keeps it.
+
+    With ``mesh`` every leaf is born in its tensor-parallel serving
+    sharding (``launch.shardings.param_shardings_decode_tp``): each
+    device generates only its own shard, so no device ever holds the
+    whole model — the only way a model larger than one chip can start.
+    Without it the parameters land on the default device.  Either way
+    the eager per-leaf dispatch and its transient copies are gone."""
+    init = partial(init_params, cfg=cfg)
+    shardings = None
+    if mesh is not None:
+        from repro.launch.shardings import param_shardings_decode_tp
+        shardings = param_shardings_decode_tp(jax.eval_shape(init, key),
+                                              mesh)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def router_param_filter(params: Dict[str, Any]) -> Dict[str, Any]:

@@ -420,8 +420,7 @@ class ChunkedPrefill:
             self._route_chunk(chunk)
         else:
             eng._stream_keys.add((self._geom, size))
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+            with eng._dispatch_ctx():
                 self.logits, self.caches = eng._stream_chunk(
                     params=eng.params, tokens=chunk, caches=self.caches,
                     start=jnp.int32(start))
@@ -528,6 +527,9 @@ class ServeEngine:
         # collectives stay O(H·D)/O(d_model) activation combines — the
         # cache never moves.  ``mesh=None`` keeps every array
         # uncommitted: bitwise + dispatch-count identical to before.
+        # Parameters from ``MD.init_params_on_device(..., mesh)`` are
+        # born in these shardings (the commit below is then a no-op);
+        # host-side ones (checkpoints, tests) are placed here.
         self.mesh = mesh
         self._mesh_sig = PSH.mesh_signature(mesh)
         if mesh is not None:
@@ -603,7 +605,7 @@ class ServeEngine:
         # them — counters move per compiled call with zero device work.
         self._decode_attn_trace: Dict[Any, Tuple] = {}
         self._decode_kernel_stats: Dict[str, Any] = {
-            "dispatches": 0, "hit_layers": 0,
+            "dispatches": 0, "hit_layers": 0, "hit_reasons": Counter(),
             "decline_layers": Counter()}
         self.dispatch_count = 0           # compiled calls, engine lifetime
         self._decode_keys: set = set()    # expected decode executables
@@ -959,16 +961,27 @@ class ServeEngine:
         return caches, logits
 
     # -- decode-attention backend (kernel-path accounting) -----------------
-    def _attn_ctx(self):
-        """Context installing the engine's decode-attention backend
-        around one compiled decode call (nullcontext when none is
-        configured).  Every decode site — ``generate`` and the
-        scheduler's pooled tick — must go through this so the ambient
-        override state is consistent with what each executable was
-        traced under."""
-        if self.decode_attn is None:
-            return contextlib.nullcontext()
-        return MD.use_decode_attn(self.decode_attn)
+    def _dispatch_ctx(self):
+        """Context around every compiled call that may trace a Pallas
+        kernel: chunk streaming, ``generate``'s decode and the
+        scheduler's pooled tick.  It installs the engine's
+        decode-attention backend and makes the serving mesh ambient, so
+        each kernel runs per shard of the heads
+        (``distributed.sharding.over_heads``); every such site goes
+        through it, so the ambient state always matches what each
+        executable was traced under.  Donation warnings are silenced on
+        the CPU backend only, where donation is a no-op: on an
+        accelerator one means a cache was copied instead of updated in
+        place, and stays loud."""
+        stack = contextlib.ExitStack()
+        if self.mesh is not None:
+            stack.enter_context(jax.set_mesh(self.mesh))
+        if self.decode_attn is not None:
+            stack.enter_context(MD.use_decode_attn(self.decode_attn))
+        stack.enter_context(warnings.catch_warnings())
+        if jax.default_backend() == "cpu":
+            warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+        return stack
 
     def _note_decode_dispatch(self, key) -> None:
         """Account one decode dispatch against the kernel-path
@@ -990,6 +1003,7 @@ class ServeEngine:
         for event, reason in self._decode_attn_trace.get(key, ()):
             if event == "hit":
                 st["hit_layers"] += 1
+                st["hit_reasons"][reason] += 1
                 if reg is not None:
                     reg.counter("decode_kernel_hit_layers_total").inc()
             else:
@@ -1001,12 +1015,15 @@ class ServeEngine:
     def decode_kernel_summary(self) -> Dict[str, Any]:
         """Kernel-path accounting over the engine's lifetime: compiled
         decode dispatches, and per-layer hit/decline(reason) tallies
-        replayed from the adapters' trace-time decisions."""
+        replayed from the adapters' trace-time decisions.  Hit reasons
+        name the path: "pooled" (FullKV slot pool), "pooled_ring" (SA
+        ring slot pool), "shared" (batch-synchronous ``generate``)."""
         st = self._decode_kernel_stats
         return {
             "installed": self.decode_attn is not None,
             "dispatches": st["dispatches"],
             "hit_layers": st["hit_layers"],
+            "hit_reasons": dict(st["hit_reasons"]),
             "decline_layers": dict(st["decline_layers"]),
         }
 
@@ -1579,10 +1596,7 @@ class ServeEngine:
                                    duo_layers, enc_out, rng,
                                    mesh_sig=self._mesh_sig)
         self._decode_keys.add(dk)
-        with warnings.catch_warnings(), self._attn_ctx():
-            # donation is a no-op on backends without buffer aliasing
-            # (CPU tests) — harmless, silence the per-call warning
-            warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+        with self._dispatch_ctx():
             toks, _, _ = self._decode_many(
                 params=self.params, logits=logits, caches=caches,
                 pos=pos, rng=rng, n_steps=n_steps,

@@ -50,7 +50,6 @@ on the decode path is row-independent.
 from __future__ import annotations
 
 import time
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -705,11 +704,9 @@ class ContinuousScheduler:
                                        True, None, None, self._rng,
                                        mesh_sig=eng._mesh_sig)
             eng._decode_keys.add(dk)
-            with warnings.catch_warnings(), eng._attn_ctx():
-                # install the engine's decode backend for the pooled
-                # scan, same trace-time protocol as ``generate``;
-                # donation warnings are CPU-backend noise
-                warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
+            with eng._dispatch_ctx():
+                # the engine's decode backend and mesh for the pooled
+                # scan, same trace-time protocol as ``generate``
                 toks, logits, caches = eng._decode_many(
                     params=eng.params, logits=pool.logits,
                     caches=pool.caches, pos=pool.pos, rng=self._rng,

@@ -10,7 +10,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.decode import (lse_combine_decode,
                                       make_distributed_dot_decode)
-from repro.launch.mesh import make_debug_mesh, mesh_context
+from repro.launch.mesh import make_debug_mesh
 from repro.models import model as MD
 
 
@@ -23,7 +23,7 @@ def test_lse_combine_matches_local():
     valid = jnp.arange(S) <= 40
     mesh = make_debug_mesh(1, 1)
     # the mesh is passed explicitly (shard_map mesh=...) — no ambient
-    # jax.set_mesh needed, which also keeps jax 0.4.x compatibility
+    # jax.set_mesh needed
     out = lse_combine_decode(q, k, v, valid, mesh, ("data",))
     ref = MD._dot_decode(q, k, v, valid)
     assert float(jnp.abs(out - ref).max()) < 2e-5
@@ -71,24 +71,25 @@ def test_make_debug_mesh_raises_when_devices_short():
 
 
 def test_mesh_context_is_usable_on_any_jax_version():
-    """jax.set_mesh where it exists, the legacy ``with mesh:`` context
-    elsewhere — either way the returned object must be a working
-    context manager."""
+    """``jax.set_mesh`` activates a debug mesh as a working context
+    manager, and the sharding hints see it as the live mesh."""
+    from repro.distributed.sharding import live_mesh
     mesh = make_debug_mesh(1, 1)
-    with mesh_context(mesh):
+    assert live_mesh() is None
+    with jax.set_mesh(mesh):
+        assert live_mesh().axis_names == ("data", "model")
         out = jnp.arange(4.0) + 1
     assert float(out.sum()) == 10.0
 
 
 def test_shard_map_wrapper_accepts_both_check_kwargs():
-    """The check_vma→check_rep rename shim: both values of the flag
-    must build a callable wrapper on the installed jax version."""
-    from repro.distributed.decode import shard_map
+    """``jax.shard_map`` builds a working map with the replication
+    check on and off."""
     mesh = make_debug_mesh(1, 1)
     x = jnp.arange(4.0)
     for flag in (False, True):
-        f = shard_map(lambda a: a * 2, mesh=mesh, in_specs=(P(),),
-                      out_specs=P(), check_vma=flag)
+        f = jax.shard_map(lambda a: a * 2, mesh=mesh, in_specs=(P(),),
+                          out_specs=P(), check_vma=flag)
         assert np.array_equal(np.asarray(f(x)), np.arange(4.0) * 2)
 
 
@@ -99,9 +100,9 @@ def test_flat_axis_index_over_multi_axis_mesh():
     """Row-major flattening over ("data", "model") on a (2, 2) mesh:
     shard (d, m) gets flat index d·model_size + m, matching the device
     order of a P(("data", "model")) output sharding."""
-    from repro.distributed.decode import _flat_axis_index, shard_map
+    from repro.distributed.decode import _flat_axis_index
     mesh = make_debug_mesh(2, 2)
-    out = shard_map(
+    out = jax.shard_map(
         lambda: _flat_axis_index(("data", "model")).reshape(1),
         mesh=mesh, in_specs=(), out_specs=P(("data", "model")),
         check_vma=False)()

@@ -57,7 +57,5 @@ def test_xla_cost_analysis_undercounts_loops():
         return lax.scan(lambda h, wi: (h @ wi, None), x, w)[0]
 
     raw = jax.jit(scanned).lower(X, W).compile().cost_analysis()
-    if isinstance(raw, (list, tuple)):  # jax 0.4.x returns [dict]
-        raw = raw[0]
     # body counted once (±loop bookkeeping ops) instead of ×8
     assert float(raw["flops"]) < 1.01 * MM

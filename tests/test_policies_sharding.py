@@ -84,10 +84,10 @@ def test_param_spec_rules():
 
 def test_constrain_divisibility_fallback():
     """8 kv heads on a 16-way model axis must NOT be sharded."""
-    from repro.launch.mesh import make_debug_mesh, mesh_context
+    from repro.launch.mesh import make_debug_mesh
     mesh = make_debug_mesh(1, 1)
     rules = {"kv_heads": ("model",), "batch": ("data",)}
-    with mesh_context(mesh), logical_rules(rules):
+    with jax.set_mesh(mesh), logical_rules(rules):
         @jax.jit
         def f(x):
             return constrain(x, "batch", "kv_heads", None, None)

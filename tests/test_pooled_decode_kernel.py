@@ -58,11 +58,13 @@ def _rand(key, *shape):
 # 1. kernel parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("L,block_k", [(64, 16), (40, 16), (24, 8)])
-def test_fullkv_ragged_parity(L, block_k):
+@pytest.mark.parametrize("L,block_k,D", [(64, 16, 32), (40, 16, 32),
+                                         (24, 8, 32), (40, 16, 128)])
+def test_fullkv_ragged_parity(L, block_k, D):
     """FullKV pool: positions are arange, lengths ragged; L deliberately
-    includes non-multiples of block_k."""
-    B, Hq, Hkv, D = 4, 4, 2, 32
+    includes non-multiples of block_k.  D=128 reads (block_k, D) blocks,
+    other head dims (D, block_k) blocks of the sequence-minor view."""
+    B, Hq, Hkv = 4, 4, 2
     ks = jax.random.split(jax.random.key(0), 3)
     q = _rand(ks[0], B, Hq, 1, D)
     k = _rand(ks[1], B, Hkv, L, D)
@@ -102,10 +104,12 @@ def test_ringkv_ragged_parity_and_empty_row():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_mla_shaped_parity():
-    """MLA absorbed decode: single kv head, Dk != Dv, explicit scale."""
+@pytest.mark.parametrize("Dk,Dv", [(48, 32), (160, 128)])
+def test_mla_shaped_parity(Dk, Dv):
+    """MLA absorbed decode: single kv head, Dk != Dv (latent+rope vs
+    latent), explicit scale; (160, 128) mixes a sequence-minor K with a
+    head-dim-minor V."""
     B, Hq, L = 3, 4, 40
-    Dk, Dv = 48, 32          # latent+rope vs latent
     ks = jax.random.split(jax.random.key(2), 3)
     q = _rand(ks[0], B, Hq, 1, Dk)
     k = _rand(ks[1], B, 1, L, Dk)

@@ -99,6 +99,36 @@ def test_mesh_pooled_drain_matches_single_device(arch):
 
 
 @needs_mesh
+@pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "deepseek-v2-236b"])
+def test_mesh_drain_with_pallas_kernels_matches_single_device(arch):
+    """Both Pallas kernels installed on the mesh — the block-sparse
+    chunk kernel and the pooled decode kernel, each shard_mapped over
+    the heads (or replicated where the heads do not split, as MLA's one
+    latent) — serve the same tokens as the single-device engine with
+    the same kernels."""
+    from repro.core import modes as M
+    from repro.kernels.decode_attention import make_kernel_decode_attn
+    cfg, params = _setup(arch)
+    mesh = make_debug_mesh(1, 2)
+
+    def drain(mesh):
+        eng = ServeEngine(params, cfg, max_len=64, prefill_chunk=16,
+                          decode_attn=make_kernel_decode_attn(
+                              block_k=16, min_len=32),
+                          mesh=mesh)
+        with M.chunk_attention_backend("pallas", block=16):
+            out = _drain(eng, _mixed_requests(cfg, 4, lens=(48, 32)),
+                         slots_per_bucket=2, chunk=4)
+        assert eng.decode_kernel_summary()["hit_layers"] > 0
+        return out
+
+    out, ref = drain(mesh), drain(None)
+    assert sorted(out) == sorted(ref)
+    for rid in ref:
+        assert np.array_equal(out[rid].tokens, ref[rid].tokens), rid
+
+
+@needs_mesh
 def test_mesh_executable_guard_across_preemption_churn():
     """Admit/retire/preempt over 3 geometries on the mesh: the decode
     jit cache must end ≤ #geometries (committed shardings must not

@@ -10,7 +10,7 @@ from repro.configs.base import InputShape
 from repro.distributed import logical_rules
 from repro.launch import workloads as WL
 from repro.launch import hlo_analysis as HA
-from repro.launch.mesh import make_debug_mesh, mesh_context
+from repro.launch.mesh import make_debug_mesh
 
 SMALL = {
     "train": InputShape("t", 64, 2, "train"),
@@ -22,7 +22,7 @@ SMALL = {
 def _lower(cfg, shape, **kw):
     mesh = make_debug_mesh(1, 1)
     wl = WL.build_workload(cfg, shape, mesh, **kw)
-    with mesh_context(mesh), logical_rules(wl.rules):
+    with jax.set_mesh(mesh), logical_rules(wl.rules):
         compiled = jax.jit(wl.fn, in_shardings=wl.in_shardings).lower(
             *wl.args).compile()
         hlo = compiled.as_text()
