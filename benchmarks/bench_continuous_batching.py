@@ -164,14 +164,12 @@ def run(n_requests: int = 16, n_steps: int = 128, slots: int = 16,
     eng_t = ServeEngine(params, cfg, max_len=max_len,
                         routing_override=pattern, telemetry=True)
     _run_continuous(eng_t, reqs, arrivals, slots=slots, chunk=chunk)
-    # attribution leg: telemetry PLUS the ISSUE 9 cost-attribution
-    # layer at its default cadences — sampled tick profiler (sync
-    # boundaries only on every 32nd tick), fidelity probes on every
-    # 16th admission, and the per-tick memory ledger.  Same ≤5% bar.
+    # attribution leg: telemetry PLUS the cost-attribution layer at its
+    # default cadences — fidelity probes on every 16th admission and
+    # the per-tick memory ledger.  Same ≤5% bar.
     eng_a = ServeEngine(params, cfg, max_len=max_len,
                         routing_override=pattern, telemetry=True,
-                        profile_every=32, fidelity_probe_every=16,
-                        memory_ledger=True)
+                        fidelity_probe_every=16, memory_ledger=True)
     _run_continuous(eng_a, reqs, arrivals, slots=slots, chunk=chunk)
     # overhead is measured with every request submitted up front: the
     # off and on runs then execute the *identical* tick/batch sequence
@@ -197,7 +195,7 @@ def run(n_requests: int = 16, n_steps: int = 128, slots: int = 16,
     extra_execs = (eng_t.decode_cache_size() - eng_c.decode_cache_size())
     attr_extra_execs = (eng_a.decode_cache_size()
                         - eng_c.decode_cache_size())
-    # the profiler/ledger report artifact CI uploads: the attribution
+    # the ledger report artifact CI uploads: the attribution
     # engine's full JSON-ready report, reconciliation deltas included
     attr_report = eng_a.attribution_report()
     # probes performed = admissions the every-Nth gate sampled (first

@@ -136,5 +136,6 @@ def block_sparse_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="block_sparse_attn",
     )(sel.astype(jnp.int32), off, q, k, v)
     return out[:, :Sq]

@@ -148,6 +148,7 @@ def decode_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attn",
     )(q, k, v, pos, cur)
     return out
 
@@ -293,6 +294,7 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="pooled_decode_attn",
     )(lengths, q, k, v, positions)
     return out
 

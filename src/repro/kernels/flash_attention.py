@@ -110,5 +110,6 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attn",
     )(q, k, v)
     return out[:, :Sq]

@@ -137,5 +137,6 @@ def streaming_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="streaming_attn",
     )(q, k, v)
     return out[:, :Sq]

@@ -15,6 +15,7 @@
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 
@@ -170,17 +171,6 @@ def _write_telemetry(engine: ServeEngine, args) -> None:
         engine.export_trace(args.trace_out)
         print(f"trace   → {args.trace_out} "
               f"(open in https://ui.perfetto.dev)")
-    if args.profile_every:
-        rep = engine.profiler_report()
-        print(f"profiler: {rep['sampled_ticks']} sampled ticks "
-              f"(every {rep['every']})")
-        for ph in rep["phases"]:
-            print(f"  {ph['phase']:>14}: host={ph['host_s'] * 1e3:8.2f}ms "
-                  f"device={ph['device_s'] * 1e3:8.2f}ms "
-                  f"({ph['host_frac']:.0%} host) "
-                  f"achieved={ph['achieved_gflops_per_s']:7.1f} GFLOP/s "
-                  f"{ph['achieved_gbytes_per_s']:6.1f} GB/s "
-                  f"n={ph['count']}")
     if args.ledger_out:
         rep = engine.attribution_report()
         led = rep["ledger"]
@@ -278,17 +268,17 @@ def main() -> None:
                          "JSON here (enables engine telemetry; open in "
                          "https://ui.perfetto.dev)")
     # cost attribution (DESIGN.md §Observability); all off by default
-    ap.add_argument("--profile-every", type=int, default=0,
-                    help="sample every Nth scheduler tick for the "
-                         "host/device cost profiler (adds sync "
-                         "boundaries ONLY on sampled ticks; 0 = off)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="record a jax.profiler trace of the serving run "
+                         "here: the scheduler's serve.* spans and the "
+                         "device's ops on one clock (TensorBoard / "
+                         "Perfetto; nothing is added to the tick)")
     ap.add_argument("--fidelity-probe-every", type=int, default=0,
                     help="probe every Nth admission's attention-mass "
                          "coverage per routed layer (0 = off)")
     ap.add_argument("--ledger-out", default=None,
                     help="enable the device-memory ledger and write its "
-                         "reconciled JSON report (with the profiler "
-                         "table when --profile-every is set) here")
+                         "reconciled JSON report here")
     ap.add_argument("--mesh-model", type=int, default=0,
                     help="tensor-parallel width: shard KV heads and "
                          "attention/MLP weights over an N-way 'model' "
@@ -333,17 +323,20 @@ def main() -> None:
                          prefix_cache_mb=args.prefix_cache_mb or None,
                          prefix_cache_host_mb=args.prefix_cache_host_mb,
                          slo=slo, telemetry=telemetry,
-                         profile_every=args.profile_every,
                          fidelity_probe_every=args.fidelity_probe_every,
                          memory_ledger=bool(args.ledger_out),
                          mesh=mesh)
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
     if args.continuous:
-        _serve_continuous(engine, reqs, args)
+        with profile:
+            _serve_continuous(engine, reqs, args)
         _print_kernel_summary(engine)
         _write_telemetry(engine, args)
         return
     t0 = time.time()
-    results = serve_batch_finished(engine, reqs)
+    with profile:
+        results = serve_batch_finished(engine, reqs)
     dt = time.time() - t0
     for rid in sorted(results):
         f = results[rid]

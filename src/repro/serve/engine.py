@@ -45,7 +45,6 @@ from repro.configs.base import ModelConfig
 # that cycle at interpreter start
 from repro.core import router as RT
 from repro.distributed import pool_sharding as PSH
-from repro.launch import hlo_costs as HL
 from repro.launch import shardings as SHD
 from repro.models import model as MD
 from repro.serve import kv_cache as KC
@@ -445,10 +444,10 @@ class ChunkedPrefill:
                           routing_ctx=routing_ctx, fixed_pattern=fixed,
                           prefix_embeddings=None, encoder_frames=None,
                           fa_threshold=thr)
-        decisions = (np.asarray(pf.routing)
+        decisions = (eng.fetch(pf.routing, "routing")
                      if pf.routing is not None else None)
         self.pattern = eng._pattern(decisions, self.override)
-        self.p_fa = None if pf.p_fa is None else np.asarray(pf.p_fa)
+        self.p_fa = None if pf.p_fa is None else eng.fetch(pf.p_fa, "p_fa")
         eng._record_routing(self.pattern, self.p_fa, self.sa_level)
         # geometry from abstract shapes only — the real buffers are
         # built inside the seed jit (no eager per-admission allocs)
@@ -511,7 +510,6 @@ class ServeEngine:
                  slo: Optional[SLO.SLOConfig] = None,
                  telemetry: bool = False,
                  flight_recorder_ticks: int = 512,
-                 profile_every: int = 0,
                  fidelity_probe_every: int = 0,
                  memory_ledger: bool = False,
                  mesh=None):
@@ -554,21 +552,18 @@ class ServeEngine:
         # argmax routing) unless a scheduler's LoadTracker turns it.
         self.slo = slo if slo is not None else SLO.SLOConfig()
         self.sa_level = 0
-        # attribution layer (DESIGN.md §Observability): sampled cost
-        # profiler, routing-fidelity probes and the memory ledger.  All
-        # default off; any of them implies the telemetry surfaces exist
-        # since they export through the registry/flight recorder.
-        if profile_every < 0 or fidelity_probe_every < 0:
+        # attribution layer (DESIGN.md §Observability): routing-fidelity
+        # probes and the memory ledger.  Both default off; either implies
+        # the telemetry surfaces exist since they export through the
+        # registry/flight recorder.
+        if fidelity_probe_every < 0:
             raise ValueError(
-                f"profile_every={profile_every} / fidelity_probe_every="
-                f"{fidelity_probe_every} must be >= 0 (0 disables)")
-        self.profiler = (TM.TickProfiler(profile_every)
-                         if profile_every else None)
+                f"fidelity_probe_every={fidelity_probe_every} must be "
+                f">= 0 (0 disables)")
         self.fidelity_probe_every = int(fidelity_probe_every)
         self._probe_admissions = 0    # admissions seen by the probe dial
         self._params_cost_cache: Optional[Tuple[int, int]] = None
-        telemetry = bool(telemetry or profile_every
-                         or fidelity_probe_every or memory_ledger)
+        telemetry = bool(telemetry or fidelity_probe_every or memory_ledger)
         # decision-margin drift per (layer, sa_level) rung: pure-host
         # Welford bookkeeping fed by _record_routing, so it rides any
         # telemetry-enabled engine for free
@@ -608,6 +603,7 @@ class ServeEngine:
             "dispatches": 0, "hit_layers": 0, "hit_reasons": Counter(),
             "decline_layers": Counter()}
         self.dispatch_count = 0           # compiled calls, engine lifetime
+        self.host_syncs = 0               # blocking reads (``fetch``), lifetime
         self._decode_keys: set = set()    # expected decode executables
         self._stream_keys: set = set()    # expected (geometry, bucket)
         self._seed_keys: set = set()      # expected chunk-0 seeds
@@ -654,10 +650,6 @@ class ServeEngine:
         self._coverage = jax.jit(partial(MD.attention_mass_coverage,
                                          cfg=cfg))
         self._probe_keys: set = set()     # expected probe prompt buckets
-        # per-geometry expressed-cost specs for the profiler's analytic
-        # join (launch/hlo_costs) — derived once per pool from static
-        # cache shapes, never from device reads
-        self._cost_specs: Dict[Tuple, List[Tuple]] = {}
 
     def _build_prefix_store(self, prefix_cache_mb,
                             prefix_cache_host_mb) -> Optional[PXC.PrefixStore]:
@@ -783,6 +775,10 @@ class ServeEngine:
                     "prefill chunks streamed as tick work")
         reg.counter("serve_preemptions_total", "recompute preemptions")
         reg.counter("serve_dispatches_total", "compiled calls issued")
+        reg.counter("serve_host_syncs_total",
+                    "blocking device-to-host reads on the tick path")
+        reg.counter("serve_prompt_tokens_total",
+                    "prompt tokens streamed through prefill chunks")
         reg.counter("flux_sa_transitions_total",
                     "sparsity-dial rung changes, either direction")
         reg.gauge("prefix_store_device_bytes",
@@ -836,9 +832,9 @@ class ServeEngine:
                         sa_level: int) -> None:
         """Count per-layer FA/SA decisions and threshold-vs-score
         margins for one admission.  Called where the routing decision
-        lands on host anyway (``np.asarray(pf.routing)`` in the
-        admission paths), so this reads already-materialized host state
-        and never adds a device sync."""
+        lands on host anyway (``fetch(pf.routing)`` in the admission
+        paths), so this reads already-materialized host state and never
+        adds a device sync."""
         reg = self.telemetry
         if reg is None or pattern is None:
             return
@@ -1027,11 +1023,10 @@ class ServeEngine:
             "decline_layers": dict(st["decline_layers"]),
         }
 
-    # -- cost attribution (profiler / ledger / probes) ----------------------
+    # -- cost attribution (ledger / probes) ---------------------------------
     def _params_cost(self) -> Tuple[int, int]:
         """(parameter count, parameter bytes) — shape metadata only,
-        walked once and cached (the analytic linear-cost term and the
-        ledger's params line both read it)."""
+        walked once and cached (the ledger's params line reads it)."""
         pc = self._params_cost_cache
         if pc is None:
             leaves = jax.tree_util.tree_leaves(self.params)
@@ -1040,67 +1035,14 @@ class ServeEngine:
                 int(sum(l.size * l.dtype.itemsize for l in leaves)))
         return pc
 
-    def device_sync(self, *trees) -> None:
-        """Timed sync boundary for the profiler's sampled tick path:
-        block until every array in ``trees`` is ready.  ONLY sampled
-        ticks may call this — the unsampled path must stay sync-free
-        (DESIGN.md §Observability sampling rules)."""
-        jax.block_until_ready([t for t in trees if t is not None])
-
-    def _pool_layer_specs(self, pool) -> List[Tuple]:
-        """Per-attention-layer (buffer_len, n_q_heads, n_kv_heads, d_k,
-        d_v, dtype_bytes) specs for one slot pool, from static cache
-        shapes — the geometry half of the hlo_costs expressed-cost
-        join.  Cached per slot geometry."""
-        key = pool.slot_geometry()
-        specs = self._cost_specs.get(key)
-        if specs is None:
-            hq = self.cfg.num_heads
-            specs = []
-            for c in pool.caches:
-                if isinstance(c, KC.MambaCache):
-                    continue
-                if isinstance(c, (KC.LatentKV, KC.RingLatentKV)):
-                    # absorbed MLA decode: one latent "kv head", scores
-                    # over ckv+rope, values read from the latent
-                    specs.append((c.ckv.shape[1], hq, 1,
-                                  c.ckv.shape[-1] + c.kr.shape[-1],
-                                  c.ckv.shape[-1], c.ckv.dtype.itemsize))
-                else:  # FullKV / RingKV: k is (slots, Hkv, L, D)
-                    specs.append((c.k.shape[2], hq, c.k.shape[1],
-                                  c.k.shape[-1], c.v.shape[-1],
-                                  c.k.dtype.itemsize))
-            self._cost_specs[key] = specs
-        return specs
-
-    def _expressed_decode_cost(self, pool, dk_key, n_steps: int
-                               ) -> Dict[str, Any]:
-        """Analytic expressed FLOPs/HBM bytes for ``n_steps`` pooled
-        decode steps on ``pool`` (hlo_costs counting conventions),
-        joined with the kernel-path trace for ``dk_key`` so kernel-hit
-        layers cost their live-length block trips and declined/dense
-        layers cost the full buffer sweep.  Host arithmetic over static
-        shapes and host-known lengths — never a device read."""
-        specs = self._pool_layer_specs(pool)
-        lengths = [1] * pool.capacity  # free rows park at position 0
-        for slot, inf in pool.active.items():
-            lengths[slot] = max(
-                1, inf.metrics.prompt_len + len(inf.generated))
-        trace = self._decode_attn_trace.get(dk_key, ())
-        hits = ([e == "hit" for e, _ in trace]
-                if len(trace) == len(specs) else None)
-        attn = HL.pooled_decode_tick_cost(lengths, specs,
-                                          n_steps=n_steps,
-                                          kernel_hits=hits)
-        n_params, params_bytes = self._params_cost()
-        lin = HL.decode_linear_cost(n_params, params_bytes,
-                                    batch=pool.capacity, n_steps=n_steps)
-        return {
-            "flops": attn["flops"] + lin["flops"],
-            "hbm_bytes": attn["hbm_bytes"] + lin["hbm_bytes"],
-            "kernel_hit": attn["kernel_hit"],
-            "kernel_decline": attn["kernel_decline"],
-        }
+    def fetch(self, x, what: str) -> np.ndarray:
+        """Wait for ``x`` and copy it to the host: every blocking
+        device-to-host read of the serving path goes through here.  It
+        counts into ``host_syncs`` and runs inside a ``serve.wait`` span
+        whose ``what`` names the read."""
+        self.host_syncs += 1
+        with TR.span("serve.wait", what=what):
+            return np.asarray(x)
 
     def _maybe_fidelity_probe(self, tokens_1d, pattern
                               ) -> Optional[np.ndarray]:
@@ -1132,7 +1074,7 @@ class ServeEngine:
         cov = self._coverage(self.params, tokens=jnp.asarray(padded),
                              length=jnp.int32(S))
         self.dispatch_count += 1
-        cov = np.asarray(cov)
+        cov = self.fetch(cov, "coverage")
         reg = self.telemetry
         if reg is not None:
             for j, i in enumerate(routed):
@@ -1188,22 +1130,11 @@ class ServeEngine:
             out["prefix_store"] = self.prefix_store.stats().as_dict()
         return out
 
-    def profiler_report(self) -> Dict[str, Any]:
-        """The sampled cost profiler's achieved-vs-expressed table."""
-        if self.profiler is None:
-            raise ValueError(
-                "profiler_report: the tick profiler is disabled — "
-                "construct the ServeEngine with profile_every=N (or pass "
-                "--profile-every to launch/serve.py)")
-        return self.profiler.report()
-
     def attribution_report(self) -> Dict[str, Any]:
         """Everything the attribution layer knows, JSON-ready: the
-        profiler table, the reconciled ledger, decision-margin drift
-        and kernel-path accounting.  Disabled parts report None."""
+        reconciled ledger, decision-margin drift and kernel-path
+        accounting.  Disabled parts report None."""
         return {
-            "profiler": (self.profiler.report()
-                         if self.profiler is not None else None),
             "ledger": (self.ledger_report()
                        if self.ledger is not None else None),
             "margin_drift": (self.margin_drift.report()
@@ -1374,7 +1305,7 @@ class ServeEngine:
         on a hit, seed the job from the snapshot: caches/logits/pattern
         adopted, ``idx`` advanced past every covered chunk."""
         store, cfg = self.prefix_store, self.cfg
-        toks = np.asarray(job.tokens[0])
+        toks = self.fetch(job.tokens[0], "prompt")
         node = store.match(toks, PXC.routing_key(job.override,
                                                  job.sa_level))
         if (node is not None and job.override is None
@@ -1415,7 +1346,7 @@ class ServeEngine:
                 or size != self.prefill_chunk
                 or not self.chunked_eligible(job.seq_len, job.override)):
             return
-        toks = np.asarray(job.tokens[0])
+        toks = self.fetch(job.tokens[0], "prompt")
         if self.publish_prefix(toks, start + size, job.caches, job.logits,
                                job.pattern, p_fa=job.p_fa,
                                override=job.override,
@@ -1511,13 +1442,13 @@ class ServeEngine:
                            prefix_embeddings=prefix_embeddings,
                            encoder_frames=encoder_frames,
                            fa_threshold=thr)
-        decisions = (np.asarray(pf.routing)
+        decisions = (self.fetch(pf.routing, "routing")
                      if pf.routing is not None else None)
         pattern = self._pattern(decisions, override)
         if self.telemetry is not None:
             self._record_routing(
                 pattern,
-                None if pf.p_fa is None else np.asarray(pf.p_fa),
+                None if pf.p_fa is None else self.fetch(pf.p_fa, "p_fa"),
                 self.sa_level)
         seq_len = tokens.shape[1] + (prefix_embeddings.shape[1]
                                      if prefix_embeddings is not None else 0)
@@ -1573,7 +1504,7 @@ class ServeEngine:
                 prefix_embeddings=prefix_embeddings,
                 encoder_frames=encoder_frames)
             logits = pf.logits
-            p_fa = None if pf.p_fa is None else np.asarray(pf.p_fa)
+            p_fa = None if pf.p_fa is None else self.fetch(pf.p_fa, "p_fa")
             dispatches += 2  # prefill + the jitted repack
             # the monolithic repack emits compiler-chosen shardings
             # under a mesh; normalize so decode sees the pool flavor
@@ -1610,7 +1541,7 @@ class ServeEngine:
         msr_val = (sum(p == "sa" for p in routed) / len(routed)
                    if routed else float("nan"))
         return GenerationResult(
-            tokens=np.asarray(toks), routing=pattern,
+            tokens=self.fetch(toks, "tokens"), routing=pattern,
             msr=msr_val, kv_bytes=kv_bytes,
             p_fa=p_fa, dispatches=dispatches,
             prefix_hit_tokens=prefix_hit)

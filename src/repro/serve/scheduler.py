@@ -226,10 +226,11 @@ class ContinuousScheduler:
         self.ticks = 0
         self.tokens_generated = 0
         self.prefill_chunk_ticks = 0  # prefill chunks streamed, lifetime
-        # telemetry bookkeeping (engine.telemetry is None ⇒ never read):
-        # non-ok lifecycle events since the last tick, a stable small-int
-        # id per geometry bucket for trace/recorder labels, and the
-        # LoadTracker.transitions watermark for the delta counter
+        # a stable small-int id per geometry bucket for span, trace and
+        # recorder labels; telemetry bookkeeping (engine.telemetry is
+        # None ⇒ never read): non-ok lifecycle events since the last
+        # tick and the LoadTracker.transitions watermark for the delta
+        # counter
         self._tm_events: List[str] = []
         self._tm_pool_ids: Dict[Tuple, int] = {}
         self._tm_transitions = 0
@@ -478,15 +479,14 @@ class ContinuousScheduler:
         return any(v.req.priority < priority and self._evictable(v)
                    for v in pool.active.values())
 
-    def _prefill_work(self, pending: List[_InFlight]) -> Tuple[int, int]:
+    def _prefill_work(self, pending: List[_InFlight]) -> int:
         """Stream up to ``prefill_chunks_per_tick`` chunks across the
         waiting requests' admission jobs, priority-then-arrival order —
         prefill is tick work on equal footing with decode chunks.
-        Returns (chunks streamed, prompt tokens streamed) so the cost
-        profiler can attribute the phase's expressed FLOPs."""
+        Returns the prompt tokens streamed."""
         eng = self.engine
         budget = self.prefill_chunks_per_tick
-        chunks = tokens_streamed = 0
+        tokens_streamed = 0
         for inf in pending:
             if budget <= 0:
                 break
@@ -508,21 +508,22 @@ class ContinuousScheduler:
                     inf.job.prefix_hit_tokens, inf.metrics.prompt_len)
                 inf.metrics.prefill_start_t = self.clock()
             while budget > 0 and not inf.job.done:
-                t0 = self.clock() if eng.tracer is not None else 0.0
-                tokens_streamed += inf.job.plan[inf.job.idx][1]
-                inf.job.step()
+                rid = inf.req.rid
+                start, size = inf.job.plan[inf.job.idx]
+                with TR.span("serve.prefill_chunk", eng.tracer,
+                             event=("prefill_chunk", TR.PID_REQUESTS, rid,
+                                    "phase", None),
+                             clock=self.clock, rid=rid, start=start,
+                             size=size):
+                    inf.job.step()
+                tokens_streamed += size
                 self.prefill_chunk_ticks += 1
-                chunks += 1
                 budget -= 1
                 if eng.telemetry is not None:
                     eng.telemetry.counter("serve_prefill_chunks_total").inc()
-                if eng.tracer is not None:
-                    eng.tracer.complete(
-                        "prefill_chunk", TR.PID_REQUESTS, inf.req.rid,
-                        t0, self.clock(), cat="phase")
             if inf.job.done and inf.metrics.prefill_done_t is None:
                 inf.metrics.prefill_done_t = self.clock()
-        return chunks, tokens_streamed
+        return tokens_streamed
 
     def _admit(self, inf: _InFlight) -> bool:
         eng = self.engine
@@ -636,70 +637,78 @@ class ContinuousScheduler:
         chunks, admit finished admissions, decode one chunk per bucket,
         retire finished slots, quarantine non-finite ones.  Returns
         every request that retired since the last tick (including
-        submission-time sheds)."""
+        submission-time sheds).
+
+        The tick is the span ``serve.tick``; each phase is a child span
+        (``serve.queue``, ``serve.prefill``, ``serve.admit``, one
+        ``serve.decode`` per pool) and every blocking read a
+        ``serve.wait`` (``engine.fetch``).  At its end the tick span
+        carries its counts: prefill chunks, prompt tokens, slots
+        decoded, host syncs and compiled calls."""
         eng = self.engine
         self.ticks += 1
-        now = self.clock()
-        tm_on = eng.telemetry is not None
-        prof = eng.profiler
-        # prof_on gates every sync boundary below: unsampled ticks take
-        # the exact dispatch/sync sequence of a profiler-off run
-        prof_on = prof is not None and prof.should_sample(self.ticks)
-        if prof_on:
-            prof.note_sampled_tick()
-        if tm_on:
-            # deltas for this tick's flight record / counters; taking
-            # them costs three attribute reads — nothing touches jax
-            tm_t0, tm_d0 = now, eng.dispatch_count
-            tm_p0, tm_tok0 = self.prefill_chunk_ticks, self.tokens_generated
-        self._expire(now)
-        if self.slo.adaptive_sparsity:
-            cap = sum(p.capacity for p in self.pools.values())
-            eng.set_sa_level(self.load.observe(
-                len(self.waiting), cap or self.slots_per_bucket))
-        # admit in (aged) priority order, oldest first within a
-        # priority.  _admit may re-queue preemption victims onto
-        # self.waiting, so iterate a snapshot and let victims wait for
-        # the next tick.
-        pending = sorted(self.waiting,
-                         key=lambda i: (-self._eff_priority(i, now),
-                                        i.metrics.arrival_t))
-        if prof_on:
-            # pure host work so far: expiry, the dial, the sort
-            prof.record("queue", host_s=self.clock() - now,
-                        count=len(pending))
-        t_pf = self.clock() if prof_on else 0.0
-        pf_chunks, pf_tokens = self._prefill_work(pending)
-        if prof_on:
-            t_host = self.clock()
-            eng.device_sync([inf.job.logits for inf in pending
-                            if inf.job is not None])
-            n_par, par_bytes = eng._params_cost()
-            prof.record("prefill_chunk",
-                        host_s=t_host - t_pf,
-                        device_s=self.clock() - t_host,
-                        flops=2.0 * n_par * pf_tokens,
-                        hbm_bytes=float(par_bytes) * pf_chunks,
-                        count=pf_chunks)
-        t_ad = self.clock() if prof_on else 0.0
-        self.waiting = []
-        n_admitted = 0
-        for inf in pending:
-            if self._admit(inf):
-                n_admitted += 1
-            else:
-                self.waiting.append(inf)
-        if prof_on:
-            t_host = self.clock()
-            eng.device_sync([p.logits for p in self.pools.values()])
-            prof.record("admit", host_s=t_host - t_ad,
-                        device_s=self.clock() - t_host,
-                        count=n_admitted)
+        with TR.span("serve.tick", eng.tracer,
+                     event=("tick", TR.PID_SCHEDULER, 0, "serve", None),
+                     clock=self.clock) as tick_span:
+            now = self.clock()
+            d0, p0 = eng.dispatch_count, self.prefill_chunk_ticks
+            s0, tok0 = eng.host_syncs, self.tokens_generated
+            with TR.span("serve.queue", waiting=len(self.waiting)):
+                self._expire(now)
+                if self.slo.adaptive_sparsity:
+                    cap = sum(p.capacity for p in self.pools.values())
+                    eng.set_sa_level(self.load.observe(
+                        len(self.waiting), cap or self.slots_per_bucket))
+                # admit in (aged) priority order, oldest first within a
+                # priority.  _admit may re-queue preemption victims onto
+                # self.waiting, so iterate a snapshot and let victims
+                # wait for the next tick.
+                pending = sorted(self.waiting,
+                                 key=lambda i: (-self._eff_priority(i, now),
+                                                i.metrics.arrival_t))
+            with TR.span("serve.prefill"):
+                prompt_tokens = self._prefill_work(pending)
+            with TR.span("serve.admit") as admit_span:
+                self.waiting = []
+                n_admitted = 0
+                for inf in pending:
+                    if self._admit(inf):
+                        n_admitted += 1
+                    else:
+                        self.waiting.append(inf)
+                admit_span.set(admitted=n_admitted)
+            decoded = 0
+            for key, pool in self.pools.items():
+                if pool.active:
+                    decoded += len(pool.active)
+                    self._decode_pool(key, pool)
+            eng._check_executable_guard()
+            counts = dict(tick=self.ticks,
+                          prefill_chunks=self.prefill_chunk_ticks - p0,
+                          prompt_tokens=prompt_tokens, decoded=decoded,
+                          syncs=eng.host_syncs - s0,
+                          dispatches=eng.dispatch_count - d0)
+            tick_span.set(**counts)
+            if eng.telemetry is not None:
+                self._tm_tick(tok0, counts)
+        done, self._announce = self._announce, []
+        return done
 
-        for key, pool in self.pools.items():
-            if not pool.active:
-                continue
-            t_decode = self.clock() if tm_on else 0.0
+    def _decode_pool(self, key: Tuple, pool: SlotPool) -> None:
+        """One compiled ``decode_many`` chunk for every slot of
+        ``pool``, its tokens and non-finite sentinel read back, finished
+        slots retired and non-finite ones quarantined."""
+        eng = self.engine
+        pi = self._tm_pool_ids.setdefault(key, len(self._tm_pool_ids))
+        rids = " ".join(str(pool.active[s].req.rid)
+                        for s in sorted(pool.active))
+        tracer = eng.tracer
+        t_decode = self.clock() if tracer is not None else 0.0
+        with TR.span("serve.decode", tracer,
+                     event=(f"decode g{pi}", TR.PID_SCHEDULER, 1, "serve",
+                            {"batch": len(pool.active),
+                             "chunk": self.chunk}),
+                     clock=self.clock, pool=pi, rids=rids):
             dk = decode_executable_key(pool.caches, pool.pos, self.chunk,
                                        True, None, None, self._rng,
                                        mesh_sig=eng._mesh_sig)
@@ -715,7 +724,6 @@ class ContinuousScheduler:
                     unroll=eng.decode_unroll)
             eng._note_decode_dispatch(dk)
             eng.dispatch_count += 1
-            t_disp = self.clock() if prof_on else 0.0
             if eng.mesh is not None:
                 # pin the decode outputs back to the pool shardings so
                 # the next tick's inputs key the SAME executable even
@@ -724,45 +732,25 @@ class ContinuousScheduler:
                 caches, logits = eng._commit_state(caches, logits)
             pool.logits, pool.caches = logits, caches
             pool.advance(self.chunk)
-            toks_np = np.asarray(toks)  # (capacity, chunk)
+            toks_np = eng.fetch(toks, "tokens")  # (capacity, chunk)
             # non-finite sentinel: one reduced (capacity,) bool per tick.
             # Fault isolation is slot-granular — a poisoned row retires
             # as ``failed`` (its garbage chunk discarded) while sibling
             # rows proceed untouched; every decode op is row-independent
             # so their streams are bitwise those of an unfaulted run.
-            finite = np.asarray(jnp.all(jnp.isfinite(pool.logits), axis=-1))
+            finite = eng.fetch(
+                jnp.all(jnp.isfinite(pool.logits), axis=-1), "finite")
             now = self.clock()
-            if prof_on:
-                # host_s = dispatch (trace lookup + call issue); device_s
-                # = the wait inside the np.asarray syncs above — no
-                # extra sync is inserted, the tick already blocks here
-                cost = eng._expressed_decode_cost(pool, dk, self.chunk)
-                prof.record("decode", host_s=t_disp - t_decode,
-                            device_s=now - t_disp,
-                            flops=cost["flops"],
-                            hbm_bytes=cost["hbm_bytes"],
-                            count=self.chunk * len(pool.active))
-                for ph in ("kernel_hit", "kernel_decline"):
-                    if cost[ph]["layers"]:
-                        prof.record(ph, flops=cost[ph]["flops"],
-                                    hbm_bytes=cost[ph]["hbm_bytes"],
-                                    count=cost[ph]["layers"])
-            if eng.tracer is not None:
+            if tracer is not None:
                 # residency spans for the slots this chunk decoded; the
                 # timestamp pair brackets dispatch→host-sync, taken
-                # around the np.asarray(toks) sync that happens anyway
-                pi = self._tm_pool_ids.setdefault(
-                    key, len(self._tm_pool_ids))
+                # around the token read that happens anyway
                 for slot, res in pool.active.items():
                     tid = pi * 1000 + slot
-                    eng.tracer.name_thread(TR.PID_SLOTS, tid,
-                                           f"g{pi}/slot{slot}",
-                                           sort_index=tid)
-                    eng.tracer.complete(f"rid{res.req.rid}", TR.PID_SLOTS,
-                                        tid, t_decode, now, cat="slot")
-                eng.tracer.complete(
-                    f"decode g{pi}", TR.PID_SCHEDULER, 1, t_decode, now,
-                    args={"batch": len(pool.active), "chunk": self.chunk})
+                    tracer.name_thread(TR.PID_SLOTS, tid,
+                                       f"g{pi}/slot{slot}", sort_index=tid)
+                    tracer.complete(f"rid{res.req.rid}", TR.PID_SLOTS,
+                                    tid, t_decode, now, cat="slot")
             for slot in sorted(pool.active):
                 inf = pool.active[slot]
                 if not finite[slot]:
@@ -781,11 +769,6 @@ class ContinuousScheduler:
                 if eos_hit or len(inf.generated) >= inf.req.n_steps:
                     self._retire(inf, SLO.STATUS_OK, now,
                                  pool=pool, slot=slot)
-        eng._check_executable_guard()
-        if tm_on:
-            self._tm_tick(t0=tm_t0, d0=tm_d0, p0=tm_p0, tok0=tm_tok0)
-        done, self._announce = self._announce, []
-        return done
 
     # -- memory ledger ------------------------------------------------------
     def _ledger_entries(self) -> List[TM.PoolLedgerEntry]:
@@ -832,11 +815,11 @@ class ContinuousScheduler:
             prefix_host_bytes=(store.host_bytes
                                if store is not None else 0))
 
-    def _tm_tick(self, t0: float, d0: int, p0: int, tok0: int) -> None:
+    def _tm_tick(self, tok0: int, counts: Dict[str, int]) -> None:
         """End-of-tick telemetry: delta counters, gauge refresh, the
-        scheduler-track tick span + counter samples, and this tick's
-        flight-recorder record.  Everything read here is host state the
-        tick already materialized."""
+        scheduler-track counter samples, and this tick's flight-recorder
+        record.  ``counts`` are the tick span's.  Everything read here
+        is host state the tick already materialized."""
         eng = self.engine
         now = self.clock()
         reg = eng.telemetry
@@ -844,7 +827,9 @@ class ContinuousScheduler:
         reg.counter("serve_ticks_total").inc()
         reg.counter("serve_tokens_generated_total").inc(
             self.tokens_generated - tok0)
-        reg.counter("serve_dispatches_total").inc(eng.dispatch_count - d0)
+        reg.counter("serve_dispatches_total").inc(counts["dispatches"])
+        reg.counter("serve_host_syncs_total").inc(counts["syncs"])
+        reg.counter("serve_prompt_tokens_total").inc(counts["prompt_tokens"])
         reg.counter("flux_sa_transitions_total",
                     "sparsity-dial rung changes, either direction").inc(
             self.load.transitions - self._tm_transitions)
@@ -870,11 +855,6 @@ class ContinuousScheduler:
         if tracer is not None:
             tracer.name_thread(TR.PID_SCHEDULER, 0, "ticks", sort_index=0)
             tracer.name_thread(TR.PID_SCHEDULER, 1, "decode", sort_index=1)
-            tracer.complete(
-                "tick", TR.PID_SCHEDULER, 0, t0, now,
-                args={"tick": self.ticks,
-                      "prefill_chunks": self.prefill_chunk_ticks - p0,
-                      "dispatches": eng.dispatch_count - d0})
             tracer.counter("queue_depth", now,
                            {"waiting": len(self.waiting)})
             tracer.counter("slots", now,
@@ -898,8 +878,8 @@ class ContinuousScheduler:
                 queue_depth=len(self.waiting),
                 n_active=self.n_active(), capacity=cap,
                 batch_by_geometry=batch,
-                prefill_chunks=self.prefill_chunk_ticks - p0,
-                dispatch_delta=eng.dispatch_count - d0,
+                prefill_chunks=counts["prefill_chunks"],
+                dispatch_delta=counts["dispatches"],
                 sa_level=eng.sa_level, pressure=self.load.pressure,
                 prefix_device_bytes=(store.device_bytes
                                      if store is not None else 0),

@@ -21,13 +21,10 @@ Host-side telemetry primitives for the serving stack:
       matches no queued work), and a device-byte high watermark.  The
       scheduler feeds it already-known host integers (static shapes ×
       occupancy); it never reads a device buffer.
-  TickProfiler — sampled per-tick latency attribution.  Every Nth tick
-      the scheduler brackets each phase (queue / prefill_chunk / admit /
-      decode, split kernel-hit vs kernel-decline) with timed
-      device-sync boundaries and records host-vs-device seconds plus
-      the analytic expressed FLOPs/HBM cost from ``launch/hlo_costs``;
-      unsampled ticks never sync.  ``report()`` emits the
-      achieved-vs-expressed efficiency table.
+
+Where the host's time goes inside a tick is not measured here: the
+tick's phases are spans on the profiler's clock (``serve/tracing.span``),
+read from a ``jax.profiler`` trace beside the device's own ops.
 
 Design rules (enforced by tests/test_telemetry.py):
 
@@ -41,8 +38,8 @@ Design rules (enforced by tests/test_telemetry.py):
     a ``deque(maxlen=…)``, and metric objects are created once and
     mutated in place.
   * Deterministic.  Any sampling decision is driven by injectable
-    per-instance state (histogram reservoir seeds, profiler/probe
-    cadence counters), never module-level randomness — the bench
+    per-instance state (histogram reservoir seeds, probe cadence
+    counters), never module-level randomness — the bench
     telemetry-overhead gate replays identical workloads and must not
     eat sampling noise.
   * Off is free.  The scheduler/engine hold ``None`` instead of these
@@ -620,98 +617,6 @@ class MemoryLedger:
 
     def last(self) -> Optional[LedgerSnapshot]:
         return self._last
-
-
-# ---------------------------------------------------------------------------
-# Per-tick cost attribution profiler
-# ---------------------------------------------------------------------------
-
-
-@dataclass
-class PhaseStat:
-    """Accumulated attribution for one tick phase across all sampled
-    ticks.  ``device_s`` is wall time between timed sync boundaries
-    (host dispatch + device compute for that phase's work); ``host_s``
-    is the phase's pure-host bookkeeping time.  ``flops``/``hbm_bytes``
-    are the analytic *expressed* cost from ``launch/hlo_costs`` for the
-    work the phase dispatched."""
-    phase: str
-    ticks: int = 0
-    host_s: float = 0.0
-    device_s: float = 0.0
-    flops: float = 0.0
-    hbm_bytes: float = 0.0
-    count: int = 0  # phase-specific unit (chunks, decode steps, layers)
-
-    def as_dict(self) -> Dict[str, object]:
-        d = {
-            "phase": self.phase, "ticks": self.ticks, "count": self.count,
-            "host_s": self.host_s, "device_s": self.device_s,
-            "expressed_flops": self.flops,
-            "expressed_hbm_bytes": self.hbm_bytes,
-        }
-        wall = self.host_s + self.device_s
-        d["host_frac"] = self.host_s / wall if wall > 0 else 0.0
-        # achieved-vs-expressed: what rate did the device sustain against
-        # the analytic cost the phase expressed?
-        d["achieved_gflops_per_s"] = (
-            self.flops / self.device_s / 1e9 if self.device_s > 0 else 0.0)
-        d["achieved_gbytes_per_s"] = (
-            self.hbm_bytes / self.device_s / 1e9
-            if self.device_s > 0 else 0.0)
-        return d
-
-
-class TickProfiler:
-    """Sampled per-tick latency/cost attribution.
-
-    ``should_sample(tick)`` is a modulus test on the host tick counter —
-    deterministic, so paired bench runs profile the same ticks.  On a
-    sampled tick the *scheduler* brackets each phase with its clock and
-    a device sync (the profiler itself never imports jax) and calls
-    :meth:`record`; unsampled ticks skip both the syncs and the calls
-    entirely, keeping the steady-state path dispatch-identical."""
-
-    def __init__(self, every: int = 32):
-        if every < 1:
-            raise ValueError(
-                f"TickProfiler: every={every} must be >= 1 "
-                f"(1 = profile every tick)")
-        self.every = int(every)
-        self.sampled_ticks = 0
-        self._phases: "OrderedDict[str, PhaseStat]" = OrderedDict()
-
-    def should_sample(self, tick: int) -> bool:
-        return tick % self.every == 0
-
-    def note_sampled_tick(self) -> None:
-        self.sampled_ticks += 1
-
-    def record(self, phase: str, *, host_s: float = 0.0,
-               device_s: float = 0.0, flops: float = 0.0,
-               hbm_bytes: float = 0.0, count: int = 1) -> None:
-        st = self._phases.get(phase)
-        if st is None:
-            st = self._phases[phase] = PhaseStat(phase=phase)
-        st.ticks += 1
-        st.host_s += float(host_s)
-        st.device_s += float(device_s)
-        st.flops += float(flops)
-        st.hbm_bytes += float(hbm_bytes)
-        st.count += int(count)
-
-    def report(self) -> Dict[str, object]:
-        """Per-phase achieved-vs-expressed efficiency table, JSON-ready."""
-        phases = [st.as_dict() for st in self._phases.values()]
-        total_host = sum(p["host_s"] for p in phases)
-        total_dev = sum(p["device_s"] for p in phases)
-        return {
-            "every": self.every,
-            "sampled_ticks": self.sampled_ticks,
-            "total_host_s": total_host,
-            "total_device_s": total_dev,
-            "phases": phases,
-        }
 
 
 def main(argv: Optional[List[str]] = None) -> int:

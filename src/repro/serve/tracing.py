@@ -1,10 +1,23 @@
-"""Request-span tracing with Chrome-trace / Perfetto JSON export
-(DESIGN.md §Observability).
+"""Program spans on the profiler's clock, and request-span tracing with
+Chrome-trace / Perfetto JSON export (DESIGN.md §Observability).
 
-The scheduler records each request's lifecycle as host-side span
-events in the **scheduler's clock domain** (the injectable ``clock``
-callable — ``time.monotonic`` in production, a virtual clock in
-tests):
+``span(name, tracer, **args)`` is the one span call of the serving
+stack.  It always opens a ``jax.profiler.TraceAnnotation``: under a
+profiler session (``jax.profiler.trace`` / ``launch/serve.py
+--profile-dir``) the span lands on the device trace's own clock with
+``args`` and the counts its handle ``set()``s as event stats, so a gap
+on the device can be matched to what the host was doing; with no
+session it costs well under a microsecond.  The scheduler's tick is
+spanned ``serve.tick`` with children ``serve.queue``,
+``serve.prefill`` (holding one ``serve.prefill_chunk`` per chunk),
+``serve.admit``, ``serve.decode`` (one per pool) and ``serve.wait``
+(every blocking device-to-host read, ``ServeEngine.fetch``).
+
+When the engine has a ``SpanTracer`` (telemetry on), the same call
+also appends a Chrome "X" event in the **scheduler's clock domain**
+(the injectable ``clock`` callable — ``time.monotonic`` in production,
+a virtual clock in tests).  The scheduler records each request's
+lifecycle as host-side events there:
 
   submit → queue → admit → per-prefill-chunk → per-decode-tick slot
   residency → retire (ok / timeout / shed / cancelled / failed)
@@ -24,11 +37,10 @@ layout:
   pid 3 "scheduler" — per-tick spans and counter tracks (queue depth,
       active slots, sa_level, load pressure).
 
-Everything here is host-side bookkeeping: emitting an event is a dict
-append, timestamps come from the scheduler clock, and nothing imports
-jax — tracing can never add a device sync or a compiled executable.
-The event buffer is bounded (``max_events``; overflow counts into
-``dropped`` instead of growing without bound).
+Emitting a Chrome event is a dict append and its timestamps come from
+the scheduler clock: tracing never adds a device sync or a compiled
+executable.  The event buffer is bounded (``max_events``; overflow
+counts into ``dropped`` instead of growing without bound).
 
 ``python -m repro.serve.tracing trace.json`` validates an exported
 trace against the schema check used by the tests and the CI smoke.
@@ -36,7 +48,9 @@ trace against the schema check used by the tests and the CI smoke.
 from __future__ import annotations
 
 import json
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # fixed process ids of the three tracks (stable across exports so
 # Perfetto queries / saved UI states keep working)
@@ -138,6 +152,49 @@ class SpanTracer:
         """Write the trace as Perfetto-loadable JSON."""
         with open(path, "w") as f:
             json.dump(self.to_json(), f)
+
+
+class span:
+    """A span of the serving stack: ``with span(name, tracer, **args) as
+    sp: ... sp.set(**counts)``.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **args)``; ``set``
+    attaches counts at the span's end (``set_metadata``), so both show
+    as stats of the profiler event.  Argument values are numbers or
+    strings without commas (the annotation's own separator).  With a
+    ``tracer``, ``event`` = (name, pid, tid, cat, args) is also written
+    as a Chrome "X" event between two readings of ``clock``, its args
+    updated with the counts."""
+
+    __slots__ = ("_ann", "_tracer", "_event", "_clock", "_t0", "counts")
+
+    def __init__(self, name: str, tracer: Optional[SpanTracer] = None, *,
+                 event: Optional[Tuple[str, int, int, str,
+                                       Optional[Dict]]] = None,
+                 clock: Optional[Callable[[], float]] = None, **args: Any):
+        self._ann = TraceAnnotation(name, **args)
+        self._tracer = tracer if event is not None else None
+        self._event, self._clock = event, clock
+        self._t0 = 0.0
+        self.counts: Dict[str, Any] = {}
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._clock()
+        return self
+
+    def set(self, **counts: Any) -> None:
+        self._ann.set_metadata(**counts)
+        self.counts.update(counts)
+
+    def __exit__(self, *exc) -> None:
+        if self._tracer is not None:
+            name, pid, tid, cat, args = self._event
+            self._tracer.complete(name, pid, tid, self._t0, self._clock(),
+                                  cat=cat,
+                                  args={**(args or {}), **self.counts})
+        self._ann.__exit__(*exc)
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,10 @@
-"""Cost attribution: memory ledger, tick profiler, fidelity probes
-(DESIGN.md §Observability, ISSUE 9).
+"""Cost attribution: memory ledger, fidelity probes (DESIGN.md
+§Observability).
 
 The load-bearing claims:
-  1. attribution OFF is free — and attribution ON (profiler + ledger,
-     no probes) is *still* bitwise-identical on tokens, dispatch counts
-     and the decode-executable census: the profiler adds sync
-     boundaries only on sampled ticks, never dispatches, and the
-     ledger is pure host arithmetic;
+  1. attribution OFF is free — and attribution ON (ledger, no probes)
+     is *still* bitwise-identical on tokens, dispatch counts and the
+     decode-executable census: the ledger is pure host arithmetic;
   2. the ledger reconciles against an independent ``kv_cache_stats``
      walk exactly on payload and prefix tiers, and within exactly
      ``aux_bytes`` on overhead — at every tick, under churn;
@@ -56,14 +54,13 @@ def _drain(cfg, params, n=5, **engine_kw):
 
 
 # ---------------------------------------------------------------------------
-# Off is free; profiler+ledger on is bitwise and census-identical
+# Off is free; ledger on is bitwise and census-identical
 # ---------------------------------------------------------------------------
 
 def test_attribution_on_bitwise_parity_and_zero_new_executables():
     cfg, params = _setup()
     eng0, _, res0 = _drain(cfg, params)
-    eng1, _, res1 = _drain(cfg, params, profile_every=2,
-                           memory_ledger=True)
+    eng1, _, res1 = _drain(cfg, params, memory_ledger=True)
     assert set(res0) == set(res1)
     for rid in res0:
         assert np.array_equal(res0[rid].tokens, res1[rid].tokens), rid
@@ -72,19 +69,15 @@ def test_attribution_on_bitwise_parity_and_zero_new_executables():
     assert eng0.decode_cache_size() == eng1.decode_cache_size()
     assert eng0._decode_keys == eng1._decode_keys
     # the default engine holds no attribution objects at all
-    assert eng0.profiler is None and eng0.ledger is None
+    assert eng0.ledger is None
     assert eng0.fidelity_probe_every == 0
 
 
 def test_attribution_disabled_reports_raise():
     cfg, params = _setup()
     eng = ServeEngine(params, cfg, max_len=64)
-    with pytest.raises(ValueError, match="profiler is disabled"):
-        eng.profiler_report()
     with pytest.raises(ValueError, match="ledger is disabled"):
         eng.ledger_report()
-    with pytest.raises(ValueError):
-        ServeEngine(params, cfg, max_len=64, profile_every=-1)
     with pytest.raises(ValueError):
         ServeEngine(params, cfg, max_len=64, fidelity_probe_every=-1)
 
@@ -204,38 +197,6 @@ def test_prefix_store_watermarks_track_peaks():
     recon = eng.ledger_report()["reconciliation"]
     assert recon["prefix_device_delta"] == 0
     assert recon["prefix_host_delta"] == 0
-
-
-# ---------------------------------------------------------------------------
-# Tick profiler: sampling cadence, phases, expressed-cost join
-# ---------------------------------------------------------------------------
-
-def test_profiler_samples_on_cadence_with_expressed_costs():
-    cfg, params = _setup()
-    eng, sched, _ = _drain(cfg, params, profile_every=2)
-    rep = eng.profiler_report()
-    assert rep["every"] == 2
-    assert rep["sampled_ticks"] == sched.ticks // 2
-    phases = {p["phase"]: p for p in rep["phases"]}
-    assert "queue" in phases and "decode" in phases
-    dec = phases["decode"]
-    assert dec["expressed_flops"] > 0
-    assert dec["expressed_hbm_bytes"] > 0
-    assert dec["host_s"] >= 0 and dec["device_s"] >= 0
-    assert 0.0 <= dec["host_frac"] <= 1.0
-    # without a decode kernel installed every attention layer declines
-    assert "kernel_hit" not in phases
-    assert phases["kernel_decline"]["expressed_hbm_bytes"] > 0
-    # decline layers' cost is folded into the decode totals
-    assert (phases["kernel_decline"]["expressed_flops"]
-            <= dec["expressed_flops"])
-
-
-def test_profiler_validation():
-    with pytest.raises(ValueError, match="every"):
-        TM.TickProfiler(0)
-    p = TM.TickProfiler(3)
-    assert [t for t in range(1, 10) if p.should_sample(t)] == [3, 6, 9]
 
 
 # ---------------------------------------------------------------------------
