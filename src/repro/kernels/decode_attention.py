@@ -13,12 +13,14 @@ Two entry points:
   whole batch (the batch-synchronous ``generate`` path).
 * ``decode_attention_pooled_bh`` — per-row (B,) live-prefix lengths and
   (B, L) positions for the continuous-batching slot pool, where every
-  slot sits at a different decode depth.  The per-row length rides in
-  as a scalar-prefetch operand so the KV BlockSpec index map clamps
-  dead grid steps onto the last live block (the pipeline elides the
-  repeat fetch → expressed HBM traffic scales with the live prefix) and
-  ``pl.when`` short-circuits their compute — block *skipping*, not
-  masking.
+  slot sits at a different decode depth.  A grid row is one (slot, KV
+  head) with its G = Hq/Hkv query heads as one tile, so each K/V block
+  is fetched once per KV head, not once per query head.  The slot's
+  length rides in as a scalar-prefetch operand so the KV BlockSpec
+  index map clamps dead grid steps onto the last live block (the
+  pipeline elides the repeat fetch → expressed HBM traffic scales with
+  the live prefix) and ``pl.when`` short-circuits their compute — block
+  *skipping*, not masking.
 
 The decode phase is memory-bandwidth bound; the kernel's useful work
 per HBM byte is fixed, so the paper's speedup comes from the *shape*
@@ -155,15 +157,18 @@ def decode_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                    acc, m_scr, l_scr, *, scale: float, block_k: int,
-                   n_heads: int, k_seq_minor: bool, v_seq_minor: bool):
-    """Grid (B·Hq, max-blocks); row b serves (batch b//Hq, head b%Hq).
+                   n_kv_heads: int, k_seq_minor: bool, v_seq_minor: bool):
+    """Grid (B·Hkv, max-blocks); row r serves slot r//Hkv and KV head
+    r%Hkv, with that head's G query heads as one (G, D) tile.
 
     ``len_ref`` (scalar prefetch, (B,)) is the live-prefix length per
     slot; blocks past ``ceil(n / block_k)`` are short-circuited — their
     KV fetch was already clamped onto the last live block by the index
-    map, so skipped steps cost neither HBM bytes nor FLOPs.  A
+    map, so skipped steps cost neither HBM bytes nor FLOPs.  Each live
+    step is one (G, Dk)·(Dk, bk) score product, a row-wise online
+    softmax over the G rows and one (G, bk)·(bk, Dv) p·v product.  A
     ``*_seq_minor`` block arrives as (D, bk) instead of (bk, D)."""
-    b = pl.program_id(0)
+    r = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -172,24 +177,25 @@ def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    n = len_ref[b // n_heads]
+    n = len_ref[r // n_kv_heads]
     nb = (n + block_k - 1) // block_k     # per-row traced trip count
 
     @pl.when(j < nb)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # (1, Dk) — single token
+        q = q_ref[0].astype(jnp.float32)  # (G, Dk) — one token, G heads
         k = k_ref[0].astype(jnp.float32)  # (bk, Dk) or (Dk, bk)
         s = jax.lax.dot_general(
             q, k, (((1,), (0 if k_seq_minor else 1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32) * scale   # (G, bk)
         col = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        # live prefix ∧ occupied ring entry (-1 = empty); FullKV rows
-        # carry arange positions so only the prefix bound bites
+        # live prefix ∧ occupied ring entry (-1 = empty), one (1, bk)
+        # row shared by the G query heads; FullKV rows carry arange
+        # positions so only the prefix bound bites
         mask = (pos_ref[0] >= 0) & (col < n)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]                # (G, 1)
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -212,18 +218,21 @@ def _pooled_kernel(len_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 
 def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
                                positions: jax.Array, lengths: jax.Array,
-                               *, n_heads: int,
-                               scale: Optional[float] = None,
+                               *, scale: Optional[float] = None,
                                block_k: int = 128,
                                interpret: bool = False) -> jax.Array:
-    """Batched pooled decode: q (B·Hq, 1, Dk); k (B·Hkv, L, Dk);
-    v (B·Hkv, L, Dv); positions (B, L) int32 (-1 empty); lengths (B,)
-    int32 live-prefix counts.  Dk may differ from Dv (MLA absorbed
-    decode: Dk = R + rope, Dv = R).  Returns (B·Hq, 1, Dv)."""
-    BH, _, Dk = q.shape
-    BHkv, L = k.shape[0], k.shape[1]
+    """Batched pooled decode: q (B·Hkv, G, Dk), the G = Hq/Hkv query
+    heads of each KV head stacked (a free reshape of (B, Hq, 1, Dk):
+    query head h reads KV head h // G); k (B·Hkv, L, Dk); v (B·Hkv, L,
+    Dv); positions (B, L) int32 (-1 empty); lengths (B,) int32
+    live-prefix counts.  Dk may differ from Dv (MLA absorbed decode:
+    Dk = R + rope, Dv = R, one KV head for all query heads).  Returns
+    (B·Hkv, G, Dv).  Each K/V block is fetched once per (slot, KV
+    head), whatever G is."""
+    BHkv, G, Dk = q.shape
+    L = k.shape[1]
     Dv = v.shape[2]
-    G = BH // BHkv
+    n_kv_heads = BHkv // lengths.shape[0]
     scale = Dk ** -0.5 if scale is None else scale
     L_p = -(-L // block_k) * block_k
     if L_p != L:
@@ -236,7 +245,7 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
     # to the array's / a multiple of 128, the TPU block-shape rule that
     # a (1, block_k) block over a (B, L_p) array breaks for B > 1
     positions = positions.astype(jnp.int32)[:, None, :]
-    grid = (BH, L_p // block_k)
+    grid = (BHkv, L_p // block_k)
     # A TPU array whose head dim is not a multiple of 128 lanes is laid
     # out with its sequence dim minor (no lane padding).  Reading such a
     # K or V as (D, block_k) blocks of its (D, L) view makes the swap a
@@ -248,49 +257,49 @@ def decode_attention_pooled_bh(q: jax.Array, k: jax.Array, v: jax.Array,
     if v_seq_minor:
         v = jnp.swapaxes(v, 1, 2)
 
-    def live_block(b, j, len_ref):
+    def live_block(r, j, len_ref):
         # clamp dead steps onto the last live block: the pipeline sees
         # the same block index as the previous step and elides the
         # fetch, so HBM traffic tracks ceil(n / block_k), not L/block_k
-        n = len_ref[b // n_heads]
+        n = len_ref[r // n_kv_heads]
         nb = jnp.maximum((n + block_k - 1) // block_k, 1)
         return jnp.minimum(j, nb - 1)
 
-    def kv_map(b, j, len_ref):
-        return (b // G, live_block(b, j, len_ref), 0)
+    def kv_map(r, j, len_ref):
+        return (r, live_block(r, j, len_ref), 0)
 
-    def kv_map_seq_minor(b, j, len_ref):
-        return (b // G, 0, live_block(b, j, len_ref))
+    def kv_map_seq_minor(r, j, len_ref):
+        return (r, 0, live_block(r, j, len_ref))
 
     def kv_spec(D, seq_minor):
         if seq_minor:
             return pl.BlockSpec((1, D, block_k), kv_map_seq_minor)
         return pl.BlockSpec((1, block_k, D), kv_map)
 
-    def pos_map(b, j, len_ref):
-        return (b // n_heads, 0, live_block(b, j, len_ref))
+    def pos_map(r, j, len_ref):
+        return (r // n_kv_heads, 0, live_block(r, j, len_ref))
 
     out = pl.pallas_call(
         functools.partial(_pooled_kernel, scale=scale, block_k=block_k,
-                          n_heads=n_heads, k_seq_minor=k_seq_minor,
+                          n_kv_heads=n_kv_heads, k_seq_minor=k_seq_minor,
                           v_seq_minor=v_seq_minor),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, Dk), lambda b, j, lens: (b, 0, 0)),
+                pl.BlockSpec((1, G, Dk), lambda r, j, lens: (r, 0, 0)),
                 kv_spec(Dk, k_seq_minor),
                 kv_spec(Dv, v_seq_minor),
                 pl.BlockSpec((1, 1, block_k), pos_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, Dv), lambda b, j, lens: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, G, Dv), lambda r, j, lens: (r, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((1, Dv), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((G, Dv), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((BH, 1, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BHkv, G, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -321,14 +330,16 @@ def make_kernel_decode_attn(*, block_k: int = 128,
     ``min_len`` (the dense dot wins on tiny rings) or when the mask
     shape is one the kernels don't speak (per-KV-head duo masks).
     Every install/decline decision is appended to ``fn.trace_log`` as
-    ``(event, reason)`` — the adapter is consulted at *trace* time
-    (once per attention layer per executable), so the engine drains the
-    log after each jit dispatch to drive its kernel-path counters.
+    ``(event, reason)``, a hit as ``("hit", reason, G)`` with the
+    layer's query-group size G = Hq/Hkv (per shard under a mesh) — the
+    adapter is consulted at *trace* time (once per attention layer per
+    executable), so the engine drains the log after each jit dispatch
+    to drive its kernel-path counters.
     """
-    trace_log: List[Tuple[str, str]] = []
+    trace_log: List[Tuple] = []
 
-    def _note(event: str, reason: str) -> None:
-        trace_log.append((event, reason))
+    def _note(event: str, reason: str, *group: int) -> None:
+        trace_log.append((event, reason, *group))
 
     def fn(q: jax.Array, k: jax.Array, v: jax.Array,
            valid, scale: Optional[float] = None) -> Optional[jax.Array]:
@@ -350,15 +361,15 @@ def make_kernel_decode_attn(*, block_k: int = 128,
                 Hq, Dk = q.shape[1], q.shape[3]
                 Hkv, Dv = k.shape[1], v.shape[3]
                 out = decode_attention_pooled_bh(
-                    q.reshape(B * Hq, 1, Dk), k.reshape(B * Hkv, L, Dk),
-                    v.reshape(B * Hkv, L, Dv), positions, lengths,
-                    n_heads=Hq, scale=scale, block_k=block_k,
+                    q.reshape(B * Hkv, Hq // Hkv, Dk),
+                    k.reshape(B * Hkv, L, Dk), v.reshape(B * Hkv, L, Dv),
+                    positions, lengths, scale=scale, block_k=block_k,
                     interpret=interp)
                 return out.reshape(B, Hq, 1, Dv)
 
             # FullKV rows ship no positions; ring rows do
             _note("hit", "pooled" if valid.positions is None
-                  else "pooled_ring")
+                  else "pooled_ring", q.shape[1] // k.shape[1])
             return over_heads(pooled, q.shape[1], k.shape[1])(
                 q, k, v, positions, valid.lengths)
         if valid.ndim != 1 or k.shape[2] < min_len:
@@ -377,11 +388,11 @@ def make_kernel_decode_attn(*, block_k: int = 128,
                 scale=scale, block_k=block_k, interpret=interp)
             return out.reshape(B, Hq, 1, D)
 
-        _note("hit", "shared")
+        _note("hit", "shared", q.shape[1] // k.shape[1])
         return over_heads(shared, q.shape[1], k.shape[1])(q, k, v,
                                                           positions)
 
-    def drain_log() -> List[Tuple[str, str]]:
+    def drain_log() -> List[Tuple]:
         out = list(trace_log)
         trace_log.clear()
         return out

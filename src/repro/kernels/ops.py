@@ -87,13 +87,16 @@ def decode_attention_pooled(q, k, v, positions, lengths, *,
                             scale: Optional[float] = None,
                             interpret: Optional[bool] = None):
     """Pooled decode: q (B,Hq,1,Dk); k/v (B,Hkv,L,D*); positions (B,L)
-    int32 (-1 empty); lengths (B,) int32 live-prefix counts."""
+    int32 (-1 empty); lengths (B,) int32 live-prefix counts.  The
+    kernel takes each KV head's Hq/Hkv query heads as one tile."""
     interpret = default_interpret() if interpret is None else interpret
-    B, H = q.shape[:2]
+    B, H, _, Dk = q.shape
+    Hkv = k.shape[1]
     out = decode_attention_pooled_bh(
-        _flatten(q), _flatten(k), _flatten(v), positions, lengths,
-        n_heads=H, scale=scale, block_k=block_k, interpret=interpret)
-    return _unflatten(out, B, H)
+        q.reshape(B * Hkv, H // Hkv, Dk), _flatten(k), _flatten(v),
+        positions, lengths, scale=scale, block_k=block_k,
+        interpret=interpret)
+    return out.reshape(B, H, 1, out.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

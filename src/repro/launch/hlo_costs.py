@@ -267,11 +267,10 @@ def loop_aware_costs(hlo_text: str) -> Cost:
 # Counting conventions (deliberately conservative for the kernel):
 #   * dense KV bytes    = B · Hkv · L_buf · (Dk + Dv) · dtype_bytes
 #     (each batch row streams the whole buffer once; heads broadcast)
-#   * kernel KV bytes   = Hq · Σ_b ceil(min(len_b, L_buf)/bk) · bk
+#   * kernel KV bytes   = Hkv · Σ_b ceil(min(len_b, L_buf)/bk) · bk
 #                         · (Dk + Dv) · dtype_bytes
-#     (the grid iterates B·Hq rows and the kv index map is keyed on
-#     b//G, so consecutive q-heads of one kv group REFETCH their
-#     blocks — the honest per-grid-step count, not the ideal one)
+#     (the grid iterates B·Hkv rows, each with its G = Hq/Hkv query
+#     heads as one tile, so a block is fetched once per kv head)
 #   * FLOPs             = 2 · (same block counts) · Hq per q-row
 # Tiny q/output traffic (B·Hq·(Dk+Dv)) is omitted from both columns.
 
@@ -298,7 +297,7 @@ def pooled_decode_attn_cost(lengths: List[int], buffer_len: int, *,
     dense_flops = 2.0 * B * n_q_heads * buffer_len * (d_k + d_v)
     kv_cols = sum(_ceil_div(min(n, buffer_len), block_k) * block_k
                   for n in lengths)
-    kernel_bytes = n_q_heads * kv_cols * row_bytes
+    kernel_bytes = n_kv_heads * kv_cols * row_bytes
     kernel_flops = 2.0 * n_q_heads * kv_cols * (d_k + d_v)
     return {
         "dense_hbm_bytes": float(dense_bytes),

@@ -154,10 +154,13 @@ def _print_kernel_summary(engine: ServeEngine) -> None:
     s = engine.decode_kernel_summary()
     hits = " ".join(f"{r}={n}" for r, n in
                     sorted(s["hit_reasons"].items())) or "none"
+    groups = " ".join(f"G{g}={n}" for g, n in
+                      sorted(s["hit_groups"].items())) or "none"
     declines = " ".join(f"{r}={n}" for r, n in
                         sorted(s["decline_layers"].items())) or "none"
     print(f"decode kernel: dispatches={s['dispatches']} "
-          f"hit_layers={s['hit_layers']} ({hits}) declines: {declines}")
+          f"hit_layers={s['hit_layers']} ({hits}; {groups}) "
+          f"declines: {declines}")
 
 
 def _write_telemetry(engine: ServeEngine, args) -> None:

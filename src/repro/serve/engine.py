@@ -601,7 +601,7 @@ class ServeEngine:
         self._decode_attn_trace: Dict[Any, Tuple] = {}
         self._decode_kernel_stats: Dict[str, Any] = {
             "dispatches": 0, "hit_layers": 0, "hit_reasons": Counter(),
-            "decline_layers": Counter()}
+            "hit_groups": Counter(), "decline_layers": Counter()}
         self.dispatch_count = 0           # compiled calls, engine lifetime
         self.host_syncs = 0               # blocking reads (``fetch``), lifetime
         self._decode_keys: set = set()    # expected decode executables
@@ -996,10 +996,12 @@ class ServeEngine:
         if fresh:
             self._decode_attn_trace[key] = tuple(fresh)
         reg = self.telemetry
-        for event, reason in self._decode_attn_trace.get(key, ()):
+        for event, reason, *group in self._decode_attn_trace.get(key, ()):
             if event == "hit":
                 st["hit_layers"] += 1
                 st["hit_reasons"][reason] += 1
+                if group:
+                    st["hit_groups"][group[0]] += 1
                 if reg is not None:
                     reg.counter("decode_kernel_hit_layers_total").inc()
             else:
@@ -1013,13 +1015,17 @@ class ServeEngine:
         decode dispatches, and per-layer hit/decline(reason) tallies
         replayed from the adapters' trace-time decisions.  Hit reasons
         name the path: "pooled" (FullKV slot pool), "pooled_ring" (SA
-        ring slot pool), "shared" (batch-synchronous ``generate``)."""
+        ring slot pool), "shared" (batch-synchronous ``generate``).
+        ``hit_groups`` counts the hit layers by query-group size G =
+        Hq/Hkv, the tile height of the pooled kernel, where the adapter
+        reports it."""
         st = self._decode_kernel_stats
         return {
             "installed": self.decode_attn is not None,
             "dispatches": st["dispatches"],
             "hit_layers": st["hit_layers"],
             "hit_reasons": dict(st["hit_reasons"]),
+            "hit_groups": dict(st["hit_groups"]),
             "decline_layers": dict(st["decline_layers"]),
         }
 

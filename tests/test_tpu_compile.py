@@ -7,7 +7,9 @@ These tests compile each kernel at real widths for a described
 ``v5e:2x2`` topology — no chip attached, nothing runs:
 
   * the pooled decode kernel at phi3-mini widths, over a FullKV buffer
-    and an SA ring, and at a head dim of 128;
+    and an SA ring, at a head dim of 128, and at the command-r-plus
+    stage's widths (12 query heads a KV head, 8 slots, a 49664 buffer
+    and a 2176 ring);
   * the block-sparse chunked-prefill kernel at phi3-mini widths;
   * both kernels shard_mapped over a 4-device (1, 4) mesh at
     stablelm-12b widths (32 query / 8 KV heads of 160), the way
@@ -31,9 +33,12 @@ BLOCK = 128
 PHI3 = dict(heads=32, kv_heads=32, head_dim=96)
 STABLELM = dict(heads=32, kv_heads=8, head_dim=160)
 HEAD_DIM_128 = dict(heads=32, kv_heads=8, head_dim=128)
+CMDR_STAGE = dict(heads=96, kv_heads=8, head_dim=128)   # G = 12
 FULL_LEN = 4224   # 4096-token context + 128 of decode, block-aligned
 RING_LEN = 2176   # sink 128 + local 2048
+STAGE_LEN = 49664  # 48k-token RAG prompt + 512 of decode
 SLOTS = 4
+STAGE_SLOTS = 8
 CHUNK = 512
 
 
@@ -76,17 +81,17 @@ def _decode_fn(q, k, v, mask, lengths, positions):
     return out
 
 
-def _decode_args(spec, length, on, heads_on):
+def _decode_args(spec, length, on, heads_on, slots=SLOTS):
     H, Hkv, D = spec["heads"], spec["kv_heads"], spec["head_dim"]
     bf = jnp.bfloat16
-    return (jax.ShapeDtypeStruct((SLOTS, H, 1, D), bf, sharding=heads_on),
-            jax.ShapeDtypeStruct((SLOTS, Hkv, length, D), bf,
+    return (jax.ShapeDtypeStruct((slots, H, 1, D), bf, sharding=heads_on),
+            jax.ShapeDtypeStruct((slots, Hkv, length, D), bf,
                                  sharding=heads_on),
-            jax.ShapeDtypeStruct((SLOTS, Hkv, length, D), bf,
+            jax.ShapeDtypeStruct((slots, Hkv, length, D), bf,
                                  sharding=heads_on),
-            jax.ShapeDtypeStruct((SLOTS, 1, length), jnp.bool_, sharding=on),
-            jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=on),
-            jax.ShapeDtypeStruct((SLOTS, length), jnp.int32, sharding=on))
+            jax.ShapeDtypeStruct((slots, 1, length), jnp.bool_, sharding=on),
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=on),
+            jax.ShapeDtypeStruct((slots, length), jnp.int32, sharding=on))
 
 
 def _chunk_fn(q, k, v, start):
@@ -109,19 +114,25 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("spec,length", [(PHI3, FULL_LEN), (PHI3, RING_LEN),
-                                         (HEAD_DIM_128, FULL_LEN)],
-                         ids=["full_kv", "ring_kv", "head_dim_128"])
-def test_pooled_decode_kernel_compiles_at_phi3_width(one_chip, spec, length):
+@pytest.mark.parametrize(
+    "spec,length,slots",
+    [(PHI3, FULL_LEN, SLOTS), (PHI3, RING_LEN, SLOTS),
+     (HEAD_DIM_128, FULL_LEN, SLOTS), (CMDR_STAGE, STAGE_LEN, STAGE_SLOTS),
+     (CMDR_STAGE, RING_LEN, STAGE_SLOTS)],
+    ids=["full_kv", "ring_kv", "head_dim_128", "cmdr_stage_full_kv",
+         "cmdr_stage_ring_kv"])
+def test_pooled_decode_kernel_compiles_at_phi3_width(one_chip, spec, length,
+                                                     slots):
     """phi3's head dim 96 keeps the cache sequence-minor, which the
     kernel reads as (D, block_k) blocks in place: the compile holds no
     temporary the size of the cache.  A head dim of 128 takes the
-    (block_k, D) blocks."""
+    (block_k, D) blocks; the command-r-plus stage widths (96 query heads
+    over 8 KV heads) take them as (12, 128) query tiles."""
     compiled = jax.jit(_decode_fn).lower(
-        *_decode_args(spec, length, one_chip, one_chip)).compile()
+        *_decode_args(spec, length, one_chip, one_chip, slots)).compile()
     _assert_kernel(compiled)
     H, D = spec["kv_heads"], spec["head_dim"]
-    cache_bytes = 2 * SLOTS * H * length * D * 2   # K and V, bf16
+    cache_bytes = 2 * slots * H * length * D * 2   # K and V, bf16
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
 
 
