@@ -1,11 +1,12 @@
 """How ``correct`` is decided: the served tokens against the reference.
 
 After the window, a sample drawn from the seed of the requests it
-finished, the longest among them, is run through the plain reference
-(``reference.py``) over prompt + served tokens.  For every served
-token the number compared is how far its reference logit lies below
-the reference's best logit at that position; the widest such gap over
-the sample is held to the cell's limit.  Greedy decoding serves each
+finished, the longest among them, is run through the configuration's
+plain reference (``spec.reference``: ``bench/reference.py`` unless the
+configuration names its own) over prompt + served tokens.  For every
+served token the number compared is how far its reference logit lies
+below the reference's best logit at that position; the widest such gap
+over the sample is held to the cell's limit.  Greedy decoding serves each
 position's argmax of the program's own bfloat16 logits, so a sound
 program only loses near-ties; a wrong token, a stale cache or a
 dropped layer loses by far more.  The routed pattern each request
@@ -17,7 +18,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench import reference
+from bench import spec
 
 
 def sample(finished: Sequence[Tuple[np.ndarray, np.ndarray]], *,
@@ -52,6 +53,7 @@ def compare(params, conf: Dict, pattern: Sequence[str],
             control: bool = False) -> Dict[str, float]:
     """Widest gap of the served tokens (and, with ``control``, of the
     tokens the float8 control puts first at the same positions)."""
+    reference = spec.reference(conf)
     served_gap, control_gap, n_tok = 0.0, 0.0, 0
     for i in picked:
         prompt, served = finished[i]

@@ -5,15 +5,16 @@ process (set-up is paid once per seed, compiles once).
 
     python3 bench/control.py --workload phi3-chat --seconds 20 --seeds 11 12 13
 
-The control is the plain reference computed in float8 (``reference.py``
-with ``fp8=True``) put in the program's place: at each position of the
-same prompts and served tokens, the token it puts first is read
-against the float32 reference.  Both the program's numbers and the
-control's go through the result line's own verdict (``run.result_line``
-with the cell's limits); each seed prints both verdicts, and the
-control's has to read ``correct: false``.  A limit lies between the
-largest reading of sound runs and the smallest reading of the
-control.  The benchmark's own runs never run the control.
+The control is the configuration's plain reference (``spec.reference``)
+computed in float8 (``fp8=True``) put in the program's place: at each
+position of the same prompts and served tokens, the token it puts
+first is read against the float32 reference.  Both the program's
+numbers and the control's go through the result line's own verdict
+(``run.result_line`` with the cell's limits); each seed prints both
+verdicts, and the control's has to read ``correct: false``.  A limit
+lies between the largest reading of sound runs and the smallest
+reading of the control.  The benchmark's own runs never run the
+control.
 """
 from __future__ import annotations
 

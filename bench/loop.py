@@ -2,7 +2,9 @@
 the reference.
 
 Set-up draws the weights from the seed, builds ``ServeEngine`` and its
-continuous scheduler with the configuration's serving settings, and
+continuous scheduler with the configuration's serving settings (on a
+cell of ``chips`` > 1, tensor-parallel over a ``(1, chips)``
+``("data", "model")`` mesh of the first ``chips`` devices), and
 warms every shape the cell's traffic can produce by serving warm-up
 requests through the same scheduler.  The window then submits each
 request when it is due and calls ``engine.step()`` until ``seconds``
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, model, stats, traffic, work
+from bench import check, model, spec, stats, traffic, work
 from bench.spec import ROOT, Cell
 
 OUT = os.path.join(ROOT, ".bench_out")
@@ -95,6 +97,7 @@ class Run:
     peaks: Optional[Dict[str, float]]
     trace: Any = None           # trace.Reduced, traced runs only
     traced_ticks: List[Tick] = field(default_factory=list)
+    device_id: int = 0          # the cell's first device: its plane is read
 
     def failed(self) -> int:
         """Requests that retired in the window other than served."""
@@ -258,15 +261,16 @@ def _warm(engine, arrivals, mix, chunk: int, vocab: int, seed: int) -> None:
             toks[:, start:start + size].block_until_ready()
 
 
-def build_engine(cell: Cell, seed: int):
+def build_engine(cell: Cell, seed: int, mesh=None):
     """(cfg, routing pattern, weights, engine with its scheduler) for the
-    cell, the weights drawn from ``seed``."""
+    cell, the weights drawn from ``seed``, born sharded over ``mesh``
+    and served tensor-parallel on it where one is given."""
     from repro.kernels.decode_attention import make_kernel_decode_attn
     from repro.serve import ServeEngine
     conf, point, serving = cell.config, cell.point, cell.config["serving"]
     cfg = model.model_config(conf)
     pattern = model.routing_pattern(conf, cfg.num_layers)
-    params = model.make_weights(cfg, seed)
+    params = model.make_weights(cfg, seed, mesh)
     jax.block_until_ready(params)
     engine = ServeEngine(
         params, cfg, max_len=point["max_len"],
@@ -274,7 +278,7 @@ def build_engine(cell: Cell, seed: int):
         routing_override=pattern,
         decode_attn=make_kernel_decode_attn(
             block_k=serving["block_k"], min_len=2 * serving["block_k"]),
-        decode_unroll=1)
+        decode_unroll=1, mesh=mesh)
     engine.scheduler(slots_per_bucket=point["slots"],
                      chunk=serving["decode_steps_per_tick"],
                      prefill_chunks_per_tick=serving[
@@ -325,9 +329,14 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     clock = CompileClock()
     conf, point, serving = cell.config, cell.point, cell.config["serving"]
-    dev = jax.devices()[0]
+    devices = jax.devices()[:cell.chips]
+    dev = devices[0]
     pk = peak_table(dev.device_kind) if dev.platform == "tpu" else None
-    cfg, pattern, params, engine = build_engine(cell, seed)
+    mesh = None
+    if cell.chips > 1:
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(1, cell.chips)
+    cfg, pattern, params, engine = build_engine(cell, seed, mesh)
     if engine_hook is not None:
         engine_hook(engine)
     arrivals = traffic.generate(cell.traffic, rate=point["rate_per_s"],
@@ -341,20 +350,25 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         srv, logs, ticks, lateness, t0, t_end = serve(
             engine, arrivals, seconds, clock, trace_dir)
     queue = srv.note_prefill()
-    mem = dev.memory_stats() or {}
+    # the fullest of the cell's devices, against its own limit
+    mem = max((d.memory_stats() or {} for d in devices),
+              key=lambda m: m.get("peak_bytes_in_use", 0))
     memory = {"peak": int(mem.get("peak_bytes_in_use", 0)),
               "limit": int(mem.get("bytes_limit", 0))}
     engine.scheduler().pools.clear()
     del engine, srv
     gc.collect()
 
-    run = Run(cell=cell, geometry=work.geometry(cfg, pattern), logs=logs,
+    count = getattr(spec.reference(conf), "matmul_params", None)
+    geometry = work.geometry(cfg, pattern, cell.chips,
+                             count(conf) if count else None)
+    run = Run(cell=cell, geometry=geometry, logs=logs,
               ticks=ticks, window=(t0, t_end), seconds=seconds,
               setup_s=setup_s, compiles=dict(clock.count),
               lateness=lateness,
               device={"platform": dev.platform, "kind": dev.device_kind,
-                      "count": len(jax.devices())},
-              memory=memory, peaks=pk)
+                      "count": len(jax.devices()), "chips": cell.chips},
+              memory=memory, peaks=pk, device_id=dev.id)
     log(f"compiles: setup={clock.count.get('setup', 0)} "
         f"({clock.seconds.get('setup', 0.0):.1f}s) "
         f"in_window={clock.count.get('window', 0)} | persistent cache "
@@ -384,7 +398,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     if trace:
         t_r = time.perf_counter()
         from bench.readers import NEEDLES
-        ops, spans, execs = TR.load(trace_dir)
+        ops, spans, execs = TR.load(trace_dir, dev.id)
         run.trace = TR.reduce(ops, spans, execs, NEEDLES)
         run.traced_ticks = [t for t in ticks if t.t0 >= t0 and t.t1 <= t_end]
         idle = ", ".join(f"{k} {v:.3f}s" for k, v in sorted(

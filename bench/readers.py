@@ -140,7 +140,8 @@ def _positions(run) -> Iterable[int]:
 
 def step_mfu(run) -> Optional[float]:
     """Model FLOPs of every token prefilled or decoded in the traced
-    window over the window's length times the chip's bf16 peak."""
+    window over the window's length times the bf16 peak of the cell's
+    chips."""
     if run.trace is None or run.peaks is None:
         return None
     chunks = traced_chunks(run)
@@ -150,7 +151,7 @@ def step_mfu(run) -> Optional[float]:
     flops = work.model_flops(run.geometry, _positions(run), logits_rows)
     if flops <= 0:
         return None
-    return 100.0 * flops / (run.trace.window_s
+    return 100.0 * flops / (run.trace.window_s * run.geometry.chips
                             * run.peaks["bf16_flops_per_s"])
 
 

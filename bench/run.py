@@ -20,7 +20,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -52,12 +51,9 @@ def end_to_end(run) -> dict:
 
 
 def _reader(name: str):
-    path = os.path.join(ROOT, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench.spec import load_module
+    return load_module(os.path.join(ROOT, "bench", "metrics", name + ".py"),
+                       f"bench_metric_{name}").read
 
 
 def result_line(run, numbers: dict, limits: dict, trace: bool,

@@ -9,7 +9,8 @@ device-to-host read); the tick span carries its counts as event stats
 ``.xplane.pb`` under ``<bench.loop.OUT>/trace/<cell>``, memoised per
 file, collects those spans with their stats, the harness's ``bench.*``
 spans (``bench.window`` brackets the traced window) and the device op
-intervals of the first TPU plane (on the CPU backend, the events that
+intervals of the plane ``bench/trace.py`` reads, that of the first of
+the cell's devices (on the CPU backend, the events that
 carry an ``hlo_module`` stat, as ``bench/trace.py`` reads them).  A
 program that records no ``serve.tick`` span in the window leaves every
 metric here None.
@@ -115,11 +116,10 @@ def from_events(host: Sequence[HostSpan],
 
 
 @functools.lru_cache(maxsize=2)
-def _load(path: str, mtime_ns: int) -> Optional[Spans]:
+def _load(path: str, mtime_ns: int, device_id: int) -> Optional[Spans]:
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    device = next((p for p in pd.planes
-                   if p.name.startswith("/device:TPU")), None)
+    device = TR.device_plane(pd.planes, device_id)
     host: List[HostSpan] = []
     ops: List[Tuple[float, float]] = []
     reads: List[float] = []
@@ -144,10 +144,11 @@ def _load(path: str, mtime_ns: int) -> Optional[Spans]:
     return from_events(host, ops, reads)
 
 
-def load(trace_dir: str) -> Optional[Spans]:
-    """The spans of the newest trace under ``trace_dir``."""
+def load(trace_dir: str, device_id: int = 0) -> Optional[Spans]:
+    """The spans of the newest trace under ``trace_dir``, beside the ops
+    of TPU ``device_id``."""
     path = TR._newest(trace_dir)
-    return _load(path, os.stat(path).st_mtime_ns)
+    return _load(path, os.stat(path).st_mtime_ns, device_id)
 
 
 def of_run(run) -> Optional[Spans]:
@@ -155,7 +156,8 @@ def of_run(run) -> Optional[Spans]:
     if run.trace is None:
         return None
     from bench import loop
-    return load(os.path.join(loop.OUT, "trace", run.cell.name))
+    return load(os.path.join(loop.OUT, "trace", run.cell.name),
+                run.device_id)
 
 
 # -- the scheduler layer's metrics -------------------------------------------

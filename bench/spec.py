@@ -4,18 +4,26 @@ A workload names a configuration (``bench/configs/<config>.json``), a
 traffic mix (``bench/traffic/<traffic>.json``) and has an operating
 point of its own (``bench/cells/<workload>.json``: rate, slots, cache
 length, correctness limits).  Per-layer metrics are readers in
-``bench/metrics/<metric>.py``.  Adding a cell, a mix or a metric adds
-files; no file here names one.
+``bench/metrics/<metric>.py``.  A configuration whose block is not the
+dense one of ``bench/reference.py`` names its own plain reference,
+``"reference": "<name>"`` → ``bench/references/<name>.py``.  Adding a
+cell, a mix, a metric or a reference adds files; no file here names
+one.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
+import re
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Dict, List
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+REFERENCES = os.path.join(BENCH, "references")
 
 
 def _load(*parts: str) -> Dict[str, Any]:
@@ -70,3 +78,42 @@ def use_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: str, name: str) -> ModuleType:
+    """The Python file at ``path``, imported once as module ``name``."""
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no file {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(conf: Dict[str, Any]) -> ModuleType:
+    """The plain reference that configuration ``conf`` is checked
+    against: ``bench/references/<name>.py`` where the file says
+    ``"reference": "<name>"``, else the dense block of
+    ``bench/reference.py``.
+
+    A reference module imports nothing of the program and defines
+    ``logits(params, conf, pattern, tokens, first, max_len, *,
+    fp8=False)``: float32 logits at positions ``first ..
+    len(tokens) - 1``, and with ``fp8`` the float8 control.  It may
+    define ``matmul_params(conf)``, the matmul parameters active per
+    token over all layers (embedding and output head left out), which
+    ``work.geometry`` then takes in place of the dense block's count."""
+    name = conf.get("reference")
+    if name is None:
+        from bench import reference as dense
+        return dense
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", name):
+        raise ValueError(f"reference {name!r} is not a name")
+    path = os.path.join(REFERENCES, f"{name}.py")
+    try:
+        return load_module(path, f"bench_reference_{name}")
+    except FileNotFoundError:
+        raise FileNotFoundError(
+            f"configuration {conf.get('name')!r} names reference "
+            f"{name!r}, and there is no file {path}") from None

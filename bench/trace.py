@@ -182,17 +182,40 @@ def _newest(trace_dir: str) -> str:
     return paths[-1]
 
 
-def load(trace_dir: str) -> Tuple[List[Op], List[Span], List[Op]]:
+def device_plane(planes, device_id: int = 0):
+    """The plane of TPU ``device_id`` (``/device:TPU:<id>``), the only
+    TPU plane of a one-chip host whatever its number, or None where the
+    trace has no TPU plane (the CPU backend).  Several TPU planes but
+    none of that device is an error: another chip of the host may have
+    run nothing of the cell."""
+    tpu = {}
+    for p in planes:
+        m = re.match(r"/device:TPU:(\d+)", p.name)
+        if m:
+            tpu.setdefault(int(m.group(1)), p)
+    if not tpu:
+        return None
+    if len(tpu) == 1:
+        return next(iter(tpu.values()))
+    if device_id not in tpu:
+        raise ValueError(f"no trace plane of TPU {device_id}; planes: "
+                         f"{sorted(p.name for p in planes)}")
+    return tpu[device_id]
+
+
+def load(trace_dir: str, device_id: int = 0
+         ) -> Tuple[List[Op], List[Span], List[Op]]:
     """(device ops, benchmark host spans, executable runs) of the newest
-    ``.xplane.pb`` under ``trace_dir``; times in seconds.  With several
-    devices, the first device plane is read."""
+    ``.xplane.pb`` under ``trace_dir``; times in seconds.  One device
+    plane is read: that of ``device_id``, the first of the cell's own
+    devices (on a multi-chip cell each chip runs its share of the same
+    programs), never another chip of the host."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(_newest(trace_dir))
     ops: List[Op] = []
     spans: List[Span] = []
     execs: List[Op] = []
-    device = next((p for p in pd.planes
-                   if p.name.startswith("/device:TPU")), None)
+    device = device_plane(pd.planes, device_id)
     for plane in pd.planes:
         on_device = device is not None and plane.name == device.name
         for line in plane.lines:

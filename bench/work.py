@@ -6,21 +6,24 @@ not Hq), each query and output once; FLOPs are 2·Hq·live·(Dk+Dv) per
 query row.  A kernel that re-reads K/V for every query head therefore
 shows a lower roofline share, never less work.
 
-``Geometry`` describes the attention of one configuration; the counts
-take per-request positions recorded on the host while serving.
+``Geometry`` describes the attention of one configuration as one chip
+of the cell holds it: on a cell of ``chips`` > 1 the heads are split
+over the chips (tensor parallel), so the kernel time read from one
+chip's trace plane is set against one chip's share of the heads.  The
+counts take per-request positions recorded on the host while serving.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Iterable, Sequence, Tuple
+from typing import Iterable, Optional, Sequence, Tuple
 
 BF16 = 2
 
 
 @dataclass(frozen=True)
 class Geometry:
-    n_heads: int          # Hq
-    n_kv_heads: int       # Hkv
+    n_heads: int          # Hq / chips: one chip's query heads
+    n_kv_heads: int       # Hkv / chips, or Hkv where chips does not divide it
     head_dim: int         # Dk = Dv
     pattern: Tuple[str, ...]   # per layer "fa" | "sa"
     sink: int
@@ -28,6 +31,7 @@ class Geometry:
     matmul_params: int    # per token, all layers, without embedding/head
     head_params: int      # d_model · vocab (the output projection)
     dtype_bytes: int = BF16
+    chips: int = 1        # chips that share each layer
 
     @property
     def ring(self) -> int:
@@ -38,18 +42,32 @@ class Geometry:
         return live if self.pattern[layer] == "fa" else min(live, self.ring)
 
 
-def geometry(cfg, pattern) -> Geometry:
+def geometry(cfg, pattern, chips: int = 1,
+             matmul_params: Optional[int] = None) -> Geometry:
+    """The attention geometry of ``cfg`` as one of ``chips`` holds it:
+    the query heads split over the chips, the KV heads too where
+    ``chips`` divides them and otherwise whole on each chip, as the
+    serving pools keep them (``repro.distributed.pool_sharding``).
+    ``matmul_params`` is the count of a configuration's own reference
+    (its ``matmul_params(conf)``); without it the dense block's."""
+    if cfg.num_heads % chips:
+        raise ValueError(f"{cfg.num_heads} query heads do not split over "
+                         f"{chips} chips")
+    hkv = cfg.num_kv_heads
     d, hd = cfg.d_model, cfg.head_dim
-    per_layer = (d * cfg.num_heads * hd * 2 + d * cfg.num_kv_heads * hd * 2
-                 + 3 * d * cfg.d_ff)
-    return Geometry(cfg.num_heads, cfg.num_kv_heads, hd, tuple(pattern),
-                    cfg.flux.sink, cfg.flux.local,
-                    per_layer * cfg.num_layers, d * cfg.vocab_size)
+    if matmul_params is None:
+        matmul_params = cfg.num_layers * (
+            d * cfg.num_heads * hd * 2 + d * hkv * hd * 2 + 3 * d * cfg.d_ff)
+    return Geometry(cfg.num_heads // chips,
+                    hkv // chips if hkv % chips == 0 else hkv, hd,
+                    tuple(pattern), cfg.flux.sink, cfg.flux.local,
+                    matmul_params, d * cfg.vocab_size, chips=chips)
 
 
 def decode_attn(g: Geometry, lives: Sequence[int]) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one decode step's attention, all layers, for
-    slots whose live lengths (after the step's insert) are ``lives``."""
+    """(FLOPs, bytes) of one chip's share of one decode step's
+    attention, all layers, for slots whose live lengths (after the
+    step's insert) are ``lives``."""
     D, flops, nbytes = g.head_dim, 0.0, 0.0
     for layer in range(len(g.pattern)):
         for live in lives:
@@ -66,8 +84,9 @@ def _causal_keys(start: int, size: int) -> int:
 
 
 def prefill_attn(g: Geometry, start: int, size: int) -> Tuple[float, float]:
-    """(FLOPs, bytes) of a prefill chunk's attention on the FA layers:
-    ``size`` queries at [start, start+size) over the causal prefix."""
+    """(FLOPs, bytes) of one chip's share of a prefill chunk's attention
+    on the FA layers: ``size`` queries at [start, start+size) over the
+    causal prefix."""
     D = g.head_dim
     n_fa = sum(p == "fa" for p in g.pattern)
     flops = 2.0 * g.n_heads * _causal_keys(start, size) * (D + D) * n_fa
@@ -78,17 +97,18 @@ def prefill_attn(g: Geometry, start: int, size: int) -> Tuple[float, float]:
 
 def model_flops(g: Geometry, positions: Iterable[int],
                 logits_rows: int) -> float:
-    """Model FLOPs for tokens at absolute ``positions``: 2 × matmul
-    parameters each, the attention the routed pattern needs (causal on
-    FA layers, at most sink + local keys on SA layers), and the output
-    projection for the ``logits_rows`` rows whose logits serving needs."""
-    D, total = g.head_dim, 0.0
+    """Model FLOPs for tokens at absolute ``positions``, on all chips:
+    2 × matmul parameters each, the attention of every query head that
+    the routed pattern needs (causal on FA layers, at most sink + local
+    keys on SA layers), and the output projection for the
+    ``logits_rows`` rows whose logits serving needs."""
+    D, total, hq = g.head_dim, 0.0, g.n_heads * g.chips
     n_fa = sum(p == "fa" for p in g.pattern)
     n_sa = len(g.pattern) - n_fa
     for p in positions:
         live = p + 1
         keys = n_fa * live + n_sa * min(live, g.ring)
-        total += 2.0 * g.matmul_params + 2.0 * g.n_heads * keys * (D + D)
+        total += 2.0 * g.matmul_params + 2.0 * hq * keys * (D + D)
     return total + 2.0 * g.head_params * logits_rows
 
 

@@ -16,12 +16,15 @@ from bench.spec import Cell  # noqa: E402
 SEED = 2 ** 33 + 7   # wider than 32 bits, as a run's seed may be
 
 
-def tiny_cell(name: str = "phi3-chat", limit: float = 1e-3) -> Cell:
+def tiny_cell(name: str = "phi3-chat", limit: float = 1e-3, chips: int = 1,
+              heads: tuple = (4, 2)) -> Cell:
+    """``heads``: (query heads, KV heads), at head_dim 16."""
     with open(os.path.join(ROOT, "bench", "configs",
                            "phi3-mini-3.8b.json")) as f:
         conf = json.load(f)
-    conf.update(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-                num_key_value_heads=2, head_dim=16, intermediate_size=128,
+    conf.update(hidden_size=64, num_hidden_layers=2,
+                num_attention_heads=heads[0], num_key_value_heads=heads[1],
+                head_dim=16, intermediate_size=128,
                 vocab_size=128, torch_dtype="float32",
                 flux={"sink": 4, "local": 28, "pool_size": 8},
                 serving={"prefill_chunk": 16, "block_k": 16,
@@ -39,5 +42,5 @@ def tiny_cell(name: str = "phi3-chat", limit: float = 1e-3) -> Cell:
         bm = json.load(f)
     per_layer = [m for m in bm["per_layer"]
                  if "workloads" not in m or name in m["workloads"]]
-    return Cell(name, 1, conf, mix, point, copy.deepcopy(bm["end_to_end"]),
-                per_layer)
+    return Cell(name, chips, conf, mix, point,
+                copy.deepcopy(bm["end_to_end"]), per_layer)
